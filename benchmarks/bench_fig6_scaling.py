@@ -52,6 +52,8 @@ def main():
     for n_dev in [1, 2, 4, 8]:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
+        # host devices only: an accelerator belongs to the parent process
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.path.join(ROOT, "src")
         out = subprocess.run([sys.executable, "-c", textwrap.dedent(_CODE)],
                              capture_output=True, text=True, env=env,

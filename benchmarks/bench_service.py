@@ -129,6 +129,7 @@ from repro.core import (
     modularity,
 )
 from repro.graph import sbm_graph
+from repro.launch.compile_cache import enable_compile_cache
 from repro.service import (
     AsyncCommunityService, BatchedLouvainEngine, ServiceConfig,
 )
@@ -770,9 +771,9 @@ def bench_stream_ingest():
         f"{ratio:.2f}x_vs_immediate")
 
 
-def _sharded_child():
-    """Runs in the 2-device subprocess: paired single-device vs sharded
-    timing on one larger graph, partitions asserted identical."""
+def _sharded_pair(n_dev: int):
+    """Paired single-device vs ``n_dev``-device sharded timing on one
+    larger graph; returns ``(t_single, t_sharded, parity)``."""
     from repro.core.distributed import louvain_sharded
 
     g = sbm_graph(n_nodes=1500, n_blocks=24, p_in=0.08, p_out=0.002,
@@ -780,7 +781,7 @@ def _sharded_child():
     cfg = LouvainConfig()
     # warm both compile caches before timing
     C1 = np.asarray(louvain(g, cfg)[0])
-    Cs = np.asarray(louvain_sharded(g, cfg, mesh=2)[0])
+    Cs = np.asarray(louvain_sharded(g, cfg, mesh=n_dev)[0])
     parity = int(np.array_equal(C1, Cs))
 
     def best_of(fn, repeats=3):
@@ -792,37 +793,52 @@ def _sharded_child():
         return best
 
     t_single = best_of(lambda: louvain(g, cfg))
-    t_sharded = best_of(lambda: louvain_sharded(g, cfg, mesh=2))
+    t_sharded = best_of(lambda: louvain_sharded(g, cfg, mesh=n_dev))
+    return t_single, t_sharded, parity
+
+
+def _sharded_child():
+    """Runs in the 2-device CPU subprocess of :func:`bench_sharded`."""
+    t_single, t_sharded, parity = _sharded_pair(2)
     print(f"SHARDED_CHILD {t_single:.6f} {t_sharded:.6f} {parity}")
 
 
 def bench_sharded():
-    """Section 8: sharded single-graph detection on a 2-device forced-host
-    mesh vs the single-device driver, measured paired in a subprocess
-    (jax pins the host device count at first init).  The partition is
-    asserted bit-identical — that is the acceptance bar; the speedup is
-    recorded informationally (``speedup_sharded_2dev``): two forced-host
-    CPU "devices" share the same cores, so the ratio reports the sharding
-    machinery's overhead ceiling here and only becomes a speedup on real
-    multi-chip meshes."""
+    """Section 8: sharded single-graph detection on a 2-device mesh vs
+    the single-device driver.  On the CPU the pair is measured in a
+    subprocess with two forced-host devices (jax pins the host device
+    count at first init); on an accelerator it runs in this process on
+    the devices it already holds — a child could not reach them — and is
+    skipped with fewer than two.  The partition is asserted
+    bit-identical — that is the acceptance bar; the speedup is recorded
+    informationally (``speedup_sharded_2dev``): two forced-host CPU
+    "devices" share the same cores, so there the ratio reports the
+    sharding machinery's overhead ceiling."""
     import os
     import subprocess
     import sys
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"{env.get('XLA_FLAGS', '')} "
-                        "--xla_force_host_platform_device_count=2").strip()
-    proc = subprocess.run(
-        [sys.executable, __file__, "--sharded-child"],
-        capture_output=True, text=True, env=env, timeout=1200)
-    if proc.returncode != 0:
-        raise SystemExit("sharded bench child failed:\n"
-                         + proc.stdout + proc.stderr)
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("SHARDED_CHILD")][-1]
-    _, t_single, t_sharded, parity = line.split()
-    t_single, t_sharded = float(t_single), float(t_sharded)
-    parity = int(parity)
+    if jax.default_backend() != "cpu":
+        if len(jax.devices()) < 2:
+            print(f"# sharded: skipped, one {jax.default_backend()} device")
+            return
+        t_single, t_sharded, parity = _sharded_pair(2)
+    else:
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = (f"{env.get('XLA_FLAGS', '')} "
+                            "--xla_force_host_platform_device_count=2"
+                            ).strip()
+        proc = subprocess.run(
+            [sys.executable, __file__, "--sharded-child"],
+            capture_output=True, text=True, env=env, timeout=1200)
+        if proc.returncode != 0:
+            raise SystemExit("sharded bench child failed:\n"
+                             + proc.stdout + proc.stderr)
+        line = [ln for ln in proc.stdout.splitlines()
+                if ln.startswith("SHARDED_CHILD")][-1]
+        _, t_single, t_sharded, parity = line.split()
+        t_single, t_sharded = float(t_single), float(t_sharded)
+        parity = int(parity)
     assert parity == 1, "sharded partition diverged from single-device"
     print("# sharded 2-device partition matches single-device exactly")
     row("service_sharded_single", t_single, f"{1.0 / t_single:.2f} graphs/s")
@@ -882,6 +898,7 @@ def bench_tiers():
 
 
 def main():
+    enable_compile_cache()
     print("name,us_per_call,derived")
     graphs, t_seq, seq = bench_engine()
     bench_async_frontend(graphs, t_seq, seq)

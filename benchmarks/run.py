@@ -25,6 +25,8 @@ def main() -> None:
                     help="comma-separated subset of " + ",".join(BENCHES))
     args = ap.parse_args()
     names = list(BENCHES) if not args.only else args.only.split(",")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failed = []

@@ -63,16 +63,6 @@ from repro.kernels import ops
 
 SDS = jax.ShapeDtypeStruct
 
-# jax >= 0.6 exposes shard_map at the top level with `check_vma`; earlier
-# releases ship it under jax.experimental with the `check_rep` spelling.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
 
 def community_pass(src, dst, w, v_lo, v_hi, two_m, n_nodes, *,
                    nv: int, axis, move_iters: int, split_iters: int,
@@ -127,13 +117,13 @@ def build_community_step(mesh, *, n_cap: int, m_shard: int,
 
     edge_spec = P(axes, None)
     scal_spec = P(axes)
-    step = _shard_map(
+    step = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(edge_spec, edge_spec, edge_spec, scal_spec, scal_spec,
                   P(), P()),
         out_specs=(P(), P(), P(), edge_spec, edge_spec, edge_spec),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
 
     args = (
@@ -272,13 +262,13 @@ def build_sharded_pass(mesh, *, nv: int, m_shard: int, m_total: int, cfg,
 
     edge_spec = P(axes, None)
     scal_spec = P(axes)
-    step = _shard_map(
+    step = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(edge_spec, edge_spec, edge_spec, edge_spec, scal_spec,
                   scal_spec, P(), P(), P()),
         out_specs=(P(), P(), P(), P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     e_sh = NamedSharding(mesh, edge_spec)
     s_sh = NamedSharding(mesh, scal_spec)

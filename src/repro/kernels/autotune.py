@@ -26,7 +26,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-DEFAULT_CANDIDATES = (256, 512, 1024, 2048)
+# whole multiples of the kernel's block granule (kernels/segsum.py), each of
+# which fits the TPU's scalar memory at the widths the core uses (D <= 2)
+DEFAULT_CANDIDATES = (1024, 2048, 4096, 8192, 16384)
 _ENV = "REPRO_AUTOTUNE_CACHE"
 _lock = threading.Lock()
 _mem_cache: dict = {}
@@ -77,8 +79,11 @@ def autotune_block_m(m: int, d: int = 1, *, op: str = "sum",
     """Best ``block_m`` for ``segreduce_sorted`` at shape ``[m, d]``.
 
     Returns the cached winner when available; otherwise times every
-    candidate (clamped to ``m``) on the current backend with a synthetic
-    sorted-run workload and persists the result.  ``impl='xla'`` shapes
+    candidate (clamped to ``m`` rounded up to the block granule) on the
+    current backend with a synthetic sorted-run workload and persists the
+    result.  A candidate that fails to compile or run raises: the ladder
+    holds only sizes the kernel supports, so a failure is a kernel fault
+    to surface, not a size to skip.  ``impl='xla'`` shapes
     are block-size-free: 0 is returned without measuring (the engine still
     records it in its compile key so a backend switch recompiles).
     """
@@ -96,6 +101,7 @@ def autotune_block_m(m: int, d: int = 1, *, op: str = "sum",
             return best
 
     from repro.kernels import ops
+    from repro.kernels.segsum import BLOCK_GRANULE
 
     rng = np.random.default_rng(0)
     ids = jnp.asarray(np.sort(rng.integers(0, max(m // 8, 1), m))
@@ -103,16 +109,12 @@ def autotune_block_m(m: int, d: int = 1, *, op: str = "sum",
     vals = jnp.asarray(rng.random((m, d), np.float32))
     nseg = max(m // 8, 1)
     timings = {}
-    cands = sorted({min(c, m) for c in candidates})
+    m_pad = -(-m // BLOCK_GRANULE) * BLOCK_GRANULE
+    cands = sorted({min(c, m_pad) for c in candidates})
     for c in cands:
         fn = jax.jit(lambda v, i, c=c: ops.segreduce_sorted(
             v, i, nseg, op=op, impl="pallas", block_m=c))
-        try:
-            timings[c] = _measure(fn, vals, ids)
-        except Exception:  # candidate invalid on this backend: skip it
-            continue
-    if not timings:
-        return min(DEFAULT_CANDIDATES)
+        timings[c] = _measure(fn, vals, ids)
     best = min(timings, key=timings.get)
     with _lock:
         cache = _load()

@@ -31,9 +31,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.segsum import cumsum_blocked, scan_identity, segscan_blocked
+from repro.kernels.segsum import (
+    BLOCK_GRANULE, cumsum_blocked, scan_identity, segscan_blocked,
+)
 from repro.kernels.spmm import bucket_spmm as _bucket_spmm_kernel
 from repro.kernels.onehot_segsum import onehot_segsum as _onehot_segsum_kernel
+
+
+# kernel block rows when the caller pins none (kernels/autotune.py tunes it)
+DEFAULT_BLOCK_M = 8192
 
 
 def _on_tpu() -> bool:
@@ -101,8 +107,10 @@ def segreduce_sorted(values, ids, num_segments, *, op: str = "sum",
     ``jax.ops.segment_*`` family uses (0 / dtype-min / dtype-max).
 
     impl: 'auto' | 'xla' | 'pallas' | 'scatter' (see module docstring).
-    block_m: Pallas kernel block rows; 0 = a backend default (the service
-    engine passes the per-bucket autotuned value — kernels/autotune.py).
+    block_m: Pallas kernel block rows, rounded up to whole
+    ``BLOCK_GRANULE``s and down to the padded input; 0 = ``DEFAULT_BLOCK_M``
+    (the service engine passes the per-bucket autotuned value —
+    kernels/autotune.py).
     All impls are bit-identical (in-order fold contract).
     """
     impl = resolve_impl(impl)
@@ -114,11 +122,12 @@ def segreduce_sorted(values, ids, num_segments, *, op: str = "sum",
     squeeze = values.ndim == 1
     v = values[:, None] if squeeze else values
     m = v.shape[0]
-    if block_m <= 0:
-        block_m = 512
-    block_m = min(block_m, m) if m > 0 else block_m
-    starts = jnp.zeros((m,), jnp.int32).at[0].set(1)
-    starts = starts.at[1:].set((ids[1:] != ids[:-1]).astype(jnp.int32))
+    # whole granules, and no more of them than the padded input holds
+    granules = -(-(block_m if block_m > 0 else DEFAULT_BLOCK_M)
+                 // BLOCK_GRANULE)
+    block_m = min(granules, max(-(-m // BLOCK_GRANULE), 1)) * BLOCK_GRANULE
+    starts = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]]
+                             ).astype(jnp.int32)
     # pad to a block multiple; padding rows start fresh runs of identity
     # values, so they can neither absorb nor leak a carry
     pad = (-m) % block_m

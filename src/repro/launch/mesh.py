@@ -10,18 +10,25 @@ parallel axis; LM workloads use data/model in the usual 2D layout with
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: shardings propagate through the shard_map'd passes as the
+    # graph drivers expect (jax.make_mesh defaults to Explicit axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a 1-D (data,) mesh (tests/examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 def flat_axes(mesh) -> tuple:

@@ -57,7 +57,10 @@ landing in three buckets, plus warm edge updates):
   over a 2-device forced-host CPU mesh through the engine's
   ``detect_sharded`` mode (re-execs itself with
   ``XLA_FLAGS=--xla_force_host_platform_device_count=2`` when the host
-  exposes fewer devices).  ``--sharded --smoke`` asserts bit-identical
+  exposes fewer CPU devices; on an accelerator it uses the first two
+  devices this process holds and fails clearly when there are fewer —
+  a child could not reach a chip its parent holds).  ``--sharded
+  --smoke`` asserts bit-identical
   partitions vs the single-device driver on every graph family, zero
   internally-disconnected communities, and a live exporter scrape
   carrying the halo-exchange counters.
@@ -109,6 +112,7 @@ import numpy as np
 
 from repro.core import DetectOptions, LouvainConfig
 from repro.graph import grid_graph, sbm_graph
+from repro.launch.compile_cache import enable_compile_cache
 from repro.service import (
     AsyncCommunityService, CommunityService, GraphUpdate, QueueFull,
     ServiceConfig,
@@ -921,6 +925,11 @@ def main_sharded(args):
     import jax
 
     if len(jax.devices()) < 2:
+        if jax.default_backend() != "cpu":
+            # this process holds the accelerator: a child could not get it
+            raise SystemExit(
+                f"--sharded needs >= 2 devices; this host has "
+                f"{len(jax.devices())} {jax.default_backend()} device(s)")
         # jax pins the host device count at first backend init — re-exec
         # with the forced-host flag so the mesh actually has 2 devices
         env = dict(os.environ)
@@ -1343,6 +1352,7 @@ def main(argv=None):
     ap.add_argument("--sub-batch", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         args.batch = 6

@@ -212,16 +212,21 @@ class BatchedLouvainEngine:
             return contextlib.nullcontext()
         return jax.profiler.trace(self.profile_dir)
 
-    def _note_compile(self, kind: str, bucket: Bucket, hit: bool,
-                      algorithm: str = "standard"):
+    def _note_compile(self, info: DispatchInfo):
+        hit = info.compile_hit
         if hit:
             self.n_compile_hits += 1
         else:
             self.n_compile_misses += 1
-        self.telemetry.counter(
-            "engine_compile", 1,
-            {"kind": kind, "bucket": f"{bucket.n_cap}x{bucket.m_cap}",
-             "tier": algorithm, "result": "hit" if hit else "miss"})
+        labels = {"kind": info.kind,
+                  "bucket": f"{info.bucket.n_cap}x{info.bucket.m_cap}",
+                  "tier": info.algorithm}
+        self.telemetry.counter("engine_compile", 1,
+                               {**labels, "result": "hit" if hit else "miss"})
+        if not hit:
+            # jit compiles lazily inside the first call
+            self.telemetry.counter("engine_compile_seconds",
+                                   info.t_call1 - info.t_call0, labels)
 
     def _note_dispatch(self, info: DispatchInfo, flat: dict, n: int):
         """Emit algorithm counters + fill gauge for a finished batch."""
@@ -432,7 +437,7 @@ class BatchedLouvainEngine:
             compile_hit=hit, t_start=t_start, t_call0=t_call0,
             t_call1=t_call1, t_sync=t_sync, algorithm=alg)
         self.last_detect_info = info
-        self._note_compile("detect", bucket, hit, alg)
+        self._note_compile(info)
         self._note_dispatch(info, flat, n)
         contract = contract_for(alg)
         return [
@@ -580,7 +585,7 @@ class BatchedLouvainEngine:
             compile_hit=hit, t_start=t_start, t_call0=t_call0,
             t_call1=t_call1, t_sync=t_sync)
         self.last_update_info = info
-        self._note_compile("update", bucket, hit)
+        self._note_compile(info)
         self._note_dispatch(info, flat, n)
         return [
             UpdateResult(

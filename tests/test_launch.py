@@ -12,13 +12,8 @@ from repro.roofline.analyze import collective_bytes, _shape_bytes
 
 
 def _fake_mesh(shape=(4, 2), axes=("data", "model")):
-    # AbstractMesh: axis sizes without devices (enough for _safe_spec).
-    # jax <= 0.4.x takes one tuple of (name, size) pairs; newer releases
-    # take (shape, axis_names) positionally.
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(shape, axes)
+    # AbstractMesh: axis sizes without devices (enough for _safe_spec)
+    return jax.sharding.AbstractMesh(shape, axes)
 
 
 RULES = ShardingRules()

@@ -216,16 +216,49 @@ def test_autotune_block_m_caches_on_disk(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
                        str(tmp_path / "autotune.json"))
     monkeypatch.setattr(autotune, "_mem_cache", {})
-    blk = autotune.autotune_block_m(2048, 2, impl="pallas",
-                                    candidates=(256, 512))
-    assert blk in (256, 512)
+    blk = autotune.autotune_block_m(4096, 2, impl="pallas",
+                                    candidates=(1024, 2048))
+    assert blk in (1024, 2048)
     assert (tmp_path / "autotune.json").exists()
     # second call must hit the cache (no re-measure): same answer
     monkeypatch.setattr(autotune, "_mem_cache", {})
-    assert autotune.autotune_block_m(2048, 2, impl="pallas",
-                                     candidates=(256, 512)) == blk
+    assert autotune.autotune_block_m(4096, 2, impl="pallas",
+                                     candidates=(1024, 2048)) == blk
     # xla shapes are block-free
-    assert autotune.autotune_block_m(2048, 2, impl="xla") == 0
+    assert autotune.autotune_block_m(4096, 2, impl="xla") == 0
+
+
+def test_autotune_failing_candidate_raises(tmp_path, monkeypatch):
+    """A candidate that does not compile is a kernel fault: it surfaces
+    instead of being skipped behind a fallback block size."""
+    from repro.kernels import autotune
+
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
+                       str(tmp_path / "autotune.json"))
+    monkeypatch.setattr(autotune, "_mem_cache", {})
+    real = ops.segreduce_sorted
+
+    def refuse_2048(*args, block_m=0, **kw):
+        if block_m == 2048:
+            raise RuntimeError("Mosaic refused block 2048")
+        return real(*args, block_m=block_m, **kw)
+
+    monkeypatch.setattr(ops, "segreduce_sorted", refuse_2048)
+    with pytest.raises(RuntimeError, match="refused"):
+        autotune.autotune_block_m(4096, 1, impl="pallas",
+                                  candidates=(1024, 2048))
+    assert not (tmp_path / "autotune.json").exists()
+
+
+def test_segreduce_parity_across_block_boundaries():
+    """Runs that straddle kernel blocks: the carry crosses grid steps in
+    order, at the smallest block and at a block wider than the input."""
+    rng = np.random.default_rng(3)
+    m = 5 * 1024 + 17
+    ids = jnp.asarray(np.sort(rng.integers(0, 40, m)).astype(np.int32))
+    v = jnp.asarray(rng.normal(size=(m, 2)).astype(np.float32))
+    for block_m in (1024, 16384):
+        _assert_all_impls_equal(v, ids, 40, "sum", block_m)
 
 
 def test_engine_compile_key_carries_backend():
