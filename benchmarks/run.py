@@ -13,7 +13,6 @@ BENCHES = {
     "table1": "benchmarks.bench_table1_graphs",
     "fig3": "benchmarks.bench_fig3_split_approaches",
     "fig4": "benchmarks.bench_fig4_baselines",
-    "fig5": "benchmarks.bench_fig5_phase_split",
     "fig6": "benchmarks.bench_fig6_scaling",
     "kernels": "benchmarks.bench_kernels",
 }

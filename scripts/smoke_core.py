@@ -4,7 +4,7 @@ import jax.numpy as jnp
 
 from repro.graph import sbm_graph, bridge_graph, ring_of_cliques
 from repro.core import (
-    LouvainConfig, louvain, louvain_staged, modularity,
+    LouvainConfig, louvain, modularity,
     disconnected_communities, split_labels,
 )
 

@@ -1,7 +1,5 @@
 """GSP-Louvain core: the paper's contribution as composable JAX modules."""
-from repro.core.louvain import (
-    LouvainConfig, louvain, louvain_impl, louvain_staged,
-)
+from repro.core.louvain import LouvainConfig, louvain, louvain_impl
 from repro.core.local_move import local_move
 from repro.core.split import split_labels
 from repro.core.aggregate import aggregate
@@ -35,7 +33,6 @@ __all__ = [
     "detect",
     "louvain",
     "louvain_impl",
-    "louvain_staged",
     "local_move",
     "split_labels",
     "aggregate",

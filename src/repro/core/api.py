@@ -1,9 +1,9 @@
 """Unified detection API: :class:`DetectOptions` + :func:`detect`.
 
 Before this module, callers picked among ``louvain`` / ``louvain_impl`` /
-``louvain_staged`` / ``disconnected_communities`` and threaded ~8 flat
-knobs (``scan``, ``seg_impl``, ``block_m``, ``dense_max_nv``, ...) through
-every layer.  Now one frozen, hashable record carries the whole detection
+``disconnected_communities`` and threaded ~8 flat knobs (``scan``,
+``seg_impl``, ``block_m``, ``dense_max_nv``, ...) through every layer.
+Now one frozen, hashable record carries the whole detection
 configuration — algorithm config, scan strategy, segment-reduction
 backend, dense-crossover thresholds, and the device mesh for the sharded
 single-graph path — and every entry point accepts it as a single

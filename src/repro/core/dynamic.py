@@ -69,6 +69,7 @@ from repro.core.modularity import modularity
 from repro.core.split import split_labels
 from repro.graph.container import Graph, from_coo, remap_vertices
 from repro.kernels import ops
+from repro.telemetry.spans import scope
 
 
 class CapacityError(ValueError):
@@ -686,6 +687,7 @@ def warm_update_impl(g: Graph, C_prev, touched, *, tau=1e-3,
     Returns a dict: ``C`` (dense int32[nv] membership), ``n_communities``,
     ``n_disconnected``, ``fraction``, ``q``, ``iterations``,
     ``n_affected``, ``split_moved`` (vertices the split pass relabelled).
+    Each phase runs under its device scope (``repro.telemetry.spans.SCOPES``).
     """
     impl = "dense" if scan == "dense" else "coo"
     active0 = affected_mask(g, C_prev, touched)
@@ -695,19 +697,24 @@ def warm_update_impl(g: Graph, C_prev, touched, *, tau=1e-3,
     # sharing; booleans, so every formulation is exact
     adj = (jnp.zeros((g.nv, g.nv), bool).at[g.src, g.dst].set(True)
            if scan == "dense" else None)
-    C, _, it = warm_local_move_impl(
-        g.src, g.dst, g.w, C_prev, two_m, active0,
-        tau=tau, max_iters=max_iters, scan=scan, adj=adj,
-        seg_impl=seg_impl, block_m=block_m,
-    )
-    labels, _ = split_labels(g.src, g.dst, g.w, C, impl=impl, adj=adj,
-                             seg_impl=seg_impl, block_m=block_m)
-    C_new, n_comms = seg.renumber(labels, g.node_mask(), g.nv)
-    det = disconnected_communities_impl(
-        g.src, g.dst, g.w, C_new, g.n_nodes, impl=impl, adj=adj,
-        seg_impl=seg_impl, block_m=block_m)
-    q = modularity(g.src, g.dst, g.w, C_new, seg_impl=seg_impl,
-                   block_m=block_m)
+    with scope("local_move"):
+        C, _, it = warm_local_move_impl(
+            g.src, g.dst, g.w, C_prev, two_m, active0,
+            tau=tau, max_iters=max_iters, scan=scan, adj=adj,
+            seg_impl=seg_impl, block_m=block_m,
+        )
+    with scope("split"):
+        labels, _ = split_labels(g.src, g.dst, g.w, C, impl=impl, adj=adj,
+                                 seg_impl=seg_impl, block_m=block_m)
+    with scope("renumber"):
+        C_new, n_comms = seg.renumber(labels, g.node_mask(), g.nv)
+    with scope("detector"):
+        det = disconnected_communities_impl(
+            g.src, g.dst, g.w, C_new, g.n_nodes, impl=impl, adj=adj,
+            seg_impl=seg_impl, block_m=block_m)
+    with scope("modularity"):
+        q = modularity(g.src, g.dst, g.w, C_new, seg_impl=seg_impl,
+                       block_m=block_m)
     return dict(
         C=C_new,
         n_communities=n_comms,

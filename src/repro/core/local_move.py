@@ -87,6 +87,7 @@ import jax.numpy as jnp
 from repro.core import _segments as seg
 from repro.distributed import collectives as col
 from repro.kernels import ops
+from repro.telemetry.spans import scope
 
 NEG = jnp.float32(-jnp.inf)
 
@@ -189,93 +190,100 @@ def _half_sweep(src, dst, w, C, K, Sigma, two_m, owned, movable, axis,
     ghost = nv - 1
 
     # --- scanCommunities: sort by (src, C[dst]); gather payloads ---------
-    cd = C[dst]
-    s_src, s_cd, perm = seg.sort_runs(src, cd)
-    s_dst = dst[perm]
-    s_w = w[perm]
-    not_self = s_src != s_dst  # exclude self-loops from scan (paper Alg. 4)
-    w_all = jnp.where(not_self, s_w, 0.0)
-    # Anchored joins: attraction toward a *target* community only counts
-    # neighbors frozen this half-sweep.  A synchronous join is thereby
-    # always anchored to a member that provably stays, which suppresses the
-    # join-while-anchor-leaves races that mass-produce internally
-    # disconnected communities under Jacobi dynamics (DESIGN.md §2).
-    w_frozen = (jnp.where(not_self & ~movable[s_dst], s_w, 0.0)
-                if anchored else w_all)
-    starts = seg.run_starts(s_src, s_cd)
-    rid = seg.run_ids(starts)
-    # pass A: both weight channels in ONE in-order run reduction
-    Wc = seg.runs_reduce(jnp.stack([w_all, w_frozen], axis=1), rid, m_cap,
-                         impl=seg_impl, block_m=block_m)
-    W_all_e = Wc[rid, 0]           # true K_{i->c}, per element of the run
-    W_frz_e = Wc[rid, 1]           # anchored K_{i->c}
+    with scope("sort"):
+        cd = C[dst]
+        s_src, s_cd, perm = seg.sort_runs(src, cd)
+        s_dst = dst[perm]
+        s_w = w[perm]
+    with scope("gain"):
+        # exclude self-loops from scan (paper Alg. 4)
+        not_self = s_src != s_dst
+        w_all = jnp.where(not_self, s_w, 0.0)
+        # Anchored joins: attraction toward a *target* community only counts
+        # neighbors frozen this half-sweep.  A synchronous join is thereby
+        # always anchored to a member that provably stays, which suppresses the
+        # join-while-anchor-leaves races that mass-produce internally
+        # disconnected communities under Jacobi dynamics (DESIGN.md §2).
+        w_frozen = (jnp.where(not_self & ~movable[s_dst], s_w, 0.0)
+                    if anchored else w_all)
+        starts = seg.run_starts(s_src, s_cd)
+        rid = seg.run_ids(starts)
+        # pass A: both weight channels in ONE in-order run reduction
+        Wc = seg.runs_reduce(jnp.stack([w_all, w_frozen], axis=1), rid, m_cap,
+                             impl=seg_impl, block_m=block_m)
+        W_all_e = Wc[rid, 0]           # true K_{i->c}, per element of the run
+        W_frz_e = Wc[rid, 1]           # anchored K_{i->c}
 
-    # --- K_{i->d}: true weight to own community (excluding self) ---------
-    # each vertex has at most ONE own run, so this is a select: one
-    # scatter-set at own-run starts (exact — no duplicate indices)
-    own_start = starts & (s_cd == C[s_src])
-    K_own = jnp.zeros(nv, jnp.float32).at[
-        jnp.where(own_start, s_src, ghost)].set(
-        jnp.where(own_start, W_all_e, 0.0), mode="drop")
-    K_own = K_own.at[ghost].set(0.0)
+        # --- K_{i->d}: true weight to own community (excluding self) ---------
+        # each vertex has at most ONE own run, so this is a select: one
+        # scatter-set at own-run starts (exact — no duplicate indices)
+        own_start = starts & (s_cd == C[s_src])
+        K_own = jnp.zeros(nv, jnp.float32).at[
+            jnp.where(own_start, s_src, ghost)].set(
+            jnp.where(own_start, W_all_e, 0.0), mode="drop")
+        K_own = K_own.at[ghost].set(0.0)
 
-    # --- delta-modularity per run representative (paper Eq. 2) -----------
-    # Score with the true attraction W_all; *gate* on having at least one
-    # frozen anchor in the target (W_frz frozen-filtered > 0), so the join
-    # stays connected even if every movable member departs simultaneously.
-    Ki = K[s_src]
-    d_of_i = C[s_src]
-    dq = (
-        2.0 * (W_all_e - K_own[s_src]) / two_m
-        - 2.0 * Ki * (Ki + Sigma[s_cd] - Sigma[d_of_i]) / (two_m * two_m)
-    )
-    valid = starts & (s_src < ghost) & (s_cd < ghost) & (s_cd != d_of_i)
-    cand = valid & (W_frz_e > 0.0) & movable[s_src] & owned[s_src]
-    if target_ok is not None:
-        cand = cand & target_ok[s_cd]
-    # 'want': the vertex has a positive move ignoring schedule gates — used
-    # to keep schedule-blocked vertices awake under pruning (a pruned vertex
-    # whose merge was blocked by an unlucky parity roll must retry, or the
-    # move is lost forever once its neighborhood goes quiet).  Zero-weight
-    # runs are excluded: cand requires W_frz > 0 <= W_all, so a zero-weight
-    # target can never become admissible and shouldn't hold a vertex awake
-    # — this also keeps the dense scan (whose cells exist iff W_all > 0)
-    # bit-equivalent even when zero-weight edges appear (refine's masked
-    # graphs, weight-delta updates).
-    base = valid & (W_all_e > 0.0)
-    # pass B: want and best fused into one 2-channel sorted segment max
-    dq2 = jnp.stack([jnp.where(base, dq, NEG), jnp.where(cand, dq, NEG)],
-                    axis=1)
-    mx = ops.segreduce_sorted(dq2, s_src, nv, op="max", impl=seg_impl,
-                              block_m=block_m)
-    want = mx[:, 0] > 0.0
-    best = mx[:, 1]
+        # --- delta-modularity per run representative (paper Eq. 2) -----------
+        # Score with the true attraction W_all; *gate* on having at least
+        # one frozen anchor in the target (W_frz frozen-filtered > 0), so the
+        # join stays connected even if every movable member departs
+        # simultaneously.
+        Ki = K[s_src]
+        d_of_i = C[s_src]
+        dq = (
+            2.0 * (W_all_e - K_own[s_src]) / two_m
+            - 2.0 * Ki * (Ki + Sigma[s_cd] - Sigma[d_of_i]) / (two_m * two_m)
+        )
+        valid = starts & (s_src < ghost) & (s_cd < ghost) & (s_cd != d_of_i)
+        cand = valid & (W_frz_e > 0.0) & movable[s_src] & owned[s_src]
+        if target_ok is not None:
+            cand = cand & target_ok[s_cd]
+        # 'want': the vertex has a positive move ignoring schedule gates —
+        # used to keep schedule-blocked vertices awake under pruning (a
+        # pruned vertex whose merge was blocked by an unlucky parity roll
+        # must retry, or the move is lost forever once its neighborhood goes
+        # quiet).  Zero-weight runs are excluded: cand requires W_frz > 0 <=
+        # W_all, so a zero-weight target can never become admissible and
+        # shouldn't hold a vertex awake — this also keeps the dense scan
+        # (whose cells exist iff W_all > 0) bit-equivalent even when
+        # zero-weight edges appear (refine's masked graphs, weight-delta
+        # updates).
+        base = valid & (W_all_e > 0.0)
+        # pass B: want and best fused into one 2-channel sorted segment max
+        dq2 = jnp.stack([jnp.where(base, dq, NEG), jnp.where(cand, dq, NEG)],
+                        axis=1)
+        mx = ops.segreduce_sorted(dq2, s_src, nv, op="max", impl=seg_impl,
+                                  block_m=block_m)
+        want = mx[:, 0] > 0.0
+        best = mx[:, 1]
 
-    # --- argmax per source vertex (min community id breaks ties) ---------
-    dq_c = jnp.where(cand, dq, NEG)
-    is_best = cand & (dq_c >= best[s_src] - 0.0)
-    c_star = ops.segreduce_sorted(
-        jnp.where(is_best, s_cd, seg.INT_MAX), s_src, nv, op="min",
-        impl=seg_impl, block_m=block_m)
-    move = (best > 0.0) & (c_star < ghost)
-    C_local = jnp.where(move, c_star.astype(jnp.int32), C)
+        # --- argmax per source vertex (min community id breaks ties) ---------
+        dq_c = jnp.where(cand, dq, NEG)
+        is_best = cand & (dq_c >= best[s_src] - 0.0)
+        c_star = ops.segreduce_sorted(
+            jnp.where(is_best, s_cd, seg.INT_MAX), s_src, nv, op="min",
+            impl=seg_impl, block_m=block_m)
+    with scope("move"):
+        move = (best > 0.0) & (c_star < ghost)
+        C_local = jnp.where(move, c_star.astype(jnp.int32), C)
 
-    # --- merge shard-local decisions (each vertex owned by one shard) ----
-    C_new = col.psum(jnp.where(owned, C_local, 0), axis)
-    C_new = C_new.at[ghost].set(ghost)
-    moved = col.psum(jnp.where(owned & move, 1, 0).astype(jnp.int32), axis) > 0
+        # --- merge shard-local decisions (each vertex owned by one shard) ----
+        C_new = col.psum(jnp.where(owned, C_local, 0), axis)
+        C_new = C_new.at[ghost].set(ghost)
+        moved = col.psum(
+            jnp.where(owned & move, 1, 0).astype(jnp.int32), axis) > 0
 
-    # --- exact Sigma recompute (synchronous) ------------------------------
-    # unsorted keys (C_new): stays an in-order XLA scatter on every backend
-    # — nv-sized, off the critical path, and in-order is what keeps Sigma
-    # bit-identical across seg_impls and the dense twin.  K and C_new are
-    # replicated here, so every shard recomputes the full Sigma identically
-    # and collective-free; a psum of owned-masked partials would fold
-    # cross-shard in a different order than the single-device scatter and
-    # break the ulp-exact sharded parity contract.
-    Sigma_new = jax.ops.segment_sum(K, C_new, num_segments=nv)
-    gain = col.psum(jnp.sum(jnp.where(owned & move, best, 0.0)), axis)
-    want = col.pmax((want & owned).astype(jnp.int32), axis) > 0
+        # --- exact Sigma recompute (synchronous) --------------------------
+        # unsorted keys (C_new): stays an in-order XLA scatter on every backend
+        # — nv-sized, off the critical path, and in-order is what keeps Sigma
+        # bit-identical across seg_impls and the dense twin.  K and C_new are
+        # replicated here, so every shard recomputes the full Sigma identically
+        # and collective-free; a psum of owned-masked partials would fold
+        # cross-shard in a different order than the single-device scatter and
+        # break the ulp-exact sharded parity contract.
+        Sigma_new = jax.ops.segment_sum(K, C_new, num_segments=nv)
+        gain = col.psum(jnp.sum(jnp.where(owned & move, best, 0.0)), axis)
+        want = col.pmax((want & owned).astype(jnp.int32), axis) > 0
     return C_new, Sigma_new, moved, gain, want
 
 
@@ -292,69 +300,75 @@ def _half_sweep_scatter(src, dst, w, C, K, Sigma, two_m, owned, movable, axis,
     ghost = nv - 1
 
     # --- scanCommunities: sort by (src, C[dst]) and reduce runs ----------
-    cd = C[dst]
-    not_self = src != dst  # exclude self-loops from scan (paper Alg. 4)
-    w_all = jnp.where(not_self, w, 0.0)
-    w_frozen = jnp.where(not_self & ~movable[dst], w, 0.0) if anchored else w_all
-    s_src, s_cd, s_wf, s_wa = seg.sort_by_key2(src, cd, w_frozen, w_all)
-    starts = seg.run_starts(s_src, s_cd)
-    rid = seg.run_ids(starts)
-    W_ic = seg.runs_reduce(s_wf, rid, m_cap, impl="scatter")
-    W_ic_all = seg.runs_reduce(s_wa, rid, m_cap, impl="scatter")
-    i_run, run_valid = seg.run_field(s_src, starts, rid, m_cap, ghost,
-                                     impl="scatter")
-    c_run, _ = seg.run_field(s_cd, starts, rid, m_cap, ghost, impl="scatter")
+    with scope("sort"):
+        cd = C[dst]
+        not_self = src != dst  # exclude self-loops from scan (paper Alg. 4)
+        w_all = jnp.where(not_self, w, 0.0)
+        w_frozen = (jnp.where(not_self & ~movable[dst], w, 0.0)
+                    if anchored else w_all)
+        s_src, s_cd, s_wf, s_wa = seg.sort_by_key2(src, cd, w_frozen, w_all)
+    with scope("gain"):
+        starts = seg.run_starts(s_src, s_cd)
+        rid = seg.run_ids(starts)
+        W_ic = seg.runs_reduce(s_wf, rid, m_cap, impl="scatter")
+        W_ic_all = seg.runs_reduce(s_wa, rid, m_cap, impl="scatter")
+        i_run, run_valid = seg.run_field(s_src, starts, rid, m_cap, ghost,
+                                         impl="scatter")
+        c_run, _ = seg.run_field(s_cd, starts, rid, m_cap, ghost,
+                                 impl="scatter")
 
-    # --- K_{i->d}: true weight to own community (excluding self) ---------
-    own = (c_run == C[i_run]) & run_valid
-    K_own = jax.ops.segment_sum(
-        jnp.where(own, W_ic_all, 0.0), i_run, num_segments=nv
-    )
+        # --- K_{i->d}: true weight to own community (excluding self) ---------
+        own = (c_run == C[i_run]) & run_valid
+        K_own = jax.ops.segment_sum(
+            jnp.where(own, W_ic_all, 0.0), i_run, num_segments=nv
+        )
 
-    # --- delta-modularity per candidate run (paper Eq. 2) ----------------
-    Ki = K[i_run]
-    d_of_i = C[i_run]
-    dq = (
-        2.0 * (W_ic_all - K_own[i_run]) / two_m
-        - 2.0 * Ki * (Ki + Sigma[c_run] - Sigma[d_of_i]) / (two_m * two_m)
-    )
-    cand = (
-        run_valid
-        & (i_run < ghost)
-        & (c_run < ghost)
-        & (c_run != d_of_i)
-        & (W_ic > 0.0)
-        & movable[i_run]
-        & owned[i_run]
-    )
-    if target_ok is not None:
-        cand = cand & target_ok[c_run]
-    base = (run_valid & (i_run < ghost) & (c_run < ghost)
-            & (c_run != d_of_i) & (W_ic_all > 0.0))
-    dq_all = jnp.where(base, dq, NEG)
-    want = jax.ops.segment_max(dq_all, i_run, num_segments=nv) > 0.0
-    dq = jnp.where(cand, dq, NEG)
+        # --- delta-modularity per candidate run (paper Eq. 2) ----------------
+        Ki = K[i_run]
+        d_of_i = C[i_run]
+        dq = (
+            2.0 * (W_ic_all - K_own[i_run]) / two_m
+            - 2.0 * Ki * (Ki + Sigma[c_run] - Sigma[d_of_i]) / (two_m * two_m)
+        )
+        cand = (
+            run_valid
+            & (i_run < ghost)
+            & (c_run < ghost)
+            & (c_run != d_of_i)
+            & (W_ic > 0.0)
+            & movable[i_run]
+            & owned[i_run]
+        )
+        if target_ok is not None:
+            cand = cand & target_ok[c_run]
+        base = (run_valid & (i_run < ghost) & (c_run < ghost)
+                & (c_run != d_of_i) & (W_ic_all > 0.0))
+        dq_all = jnp.where(base, dq, NEG)
+        want = jax.ops.segment_max(dq_all, i_run, num_segments=nv) > 0.0
+        dq = jnp.where(cand, dq, NEG)
 
-    # --- argmax per source vertex (min community id breaks ties) ---------
-    best = jax.ops.segment_max(dq, i_run, num_segments=nv)
-    is_best = cand & (dq >= best[i_run] - 0.0)
-    c_star = jax.ops.segment_min(
-        jnp.where(is_best, c_run, seg.INT_MAX), i_run, num_segments=nv
-    )
-    move = (best > 0.0) & (c_star < ghost)
-    C_local = jnp.where(move, c_star.astype(jnp.int32), C)
+        # --- argmax per source vertex (min community id breaks ties) ---------
+        best = jax.ops.segment_max(dq, i_run, num_segments=nv)
+        is_best = cand & (dq >= best[i_run] - 0.0)
+        c_star = jax.ops.segment_min(
+            jnp.where(is_best, c_run, seg.INT_MAX), i_run, num_segments=nv
+        )
+    with scope("move"):
+        move = (best > 0.0) & (c_star < ghost)
+        C_local = jnp.where(move, c_star.astype(jnp.int32), C)
 
-    # --- merge shard-local decisions (each vertex owned by one shard) ----
-    C_new = col.psum(jnp.where(owned, C_local, 0), axis)
-    C_new = C_new.at[ghost].set(ghost)
-    moved = col.psum(jnp.where(owned & move, 1, 0).astype(jnp.int32), axis) > 0
+        # --- merge shard-local decisions (each vertex owned by one shard) ----
+        C_new = col.psum(jnp.where(owned, C_local, 0), axis)
+        C_new = C_new.at[ghost].set(ghost)
+        moved = col.psum(
+            jnp.where(owned & move, 1, 0).astype(jnp.int32), axis) > 0
 
-    # --- exact Sigma recompute (synchronous) ------------------------------
-    # replicated (K, C_new) -> collective-free, bit-identical to the
-    # single-device scatter (see _half_sweep)
-    Sigma_new = jax.ops.segment_sum(K, C_new, num_segments=nv)
-    gain = col.psum(jnp.sum(jnp.where(owned & move, best, 0.0)), axis)
-    want = col.pmax((want & owned).astype(jnp.int32), axis) > 0
+        # --- exact Sigma recompute (synchronous) --------------------------
+        # replicated (K, C_new) -> collective-free, bit-identical to the
+        # single-device scatter (see _half_sweep)
+        Sigma_new = jax.ops.segment_sum(K, C_new, num_segments=nv)
+        gain = col.psum(jnp.sum(jnp.where(owned & move, best, 0.0)), axis)
+        want = col.pmax((want & owned).astype(jnp.int32), axis) > 0
     return C_new, Sigma_new, moved, gain, want
 
 
@@ -379,64 +393,70 @@ def _half_sweep_dense(src, dst, w, C, K, Sigma, two_m, owned, movable, axis,
     if valid_cell is None:
         valid_cell = (ids[:, None] < ghost) & (c_ids < ghost)
 
-    cd = C[dst]
-    not_self = src != dst  # exclude self-loops from scan (paper Alg. 4)
-    w_all = jnp.where(not_self, w, 0.0)
-    w_frozen = jnp.where(not_self & ~movable[dst], w, 0.0) if anchored else w_all
-    # One scatter pays the per-index cost once for both scans.  Complex add
-    # is componentwise IEEE f32 add, and duplicate-index updates apply in
-    # edge order — the same order the stable sort feeds segment_sum — so
-    # both components are bit-identical to the sort path's run sums.
-    packed = jax.lax.complex(w_all, w_frozen)
-    Wc = jnp.zeros((nv, nv), jnp.complex64).at[src, cd].add(packed)
-    W_all = jnp.real(Wc)       # true K_{i->c} per (vertex, community)
-    W_frz = jnp.imag(Wc)       # anchored K_{i->c}
+    with scope("gain"):
+        cd = C[dst]
+        not_self = src != dst  # exclude self-loops from scan (paper Alg. 4)
+        w_all = jnp.where(not_self, w, 0.0)
+        w_frozen = (jnp.where(not_self & ~movable[dst], w, 0.0)
+                    if anchored else w_all)
+        # One scatter pays the per-index cost once for both scans.  Complex
+        # add is componentwise IEEE f32 add, and duplicate-index updates
+        # apply in edge order — the same order the stable sort feeds
+        # segment_sum — so both components are bit-identical to the sort
+        # path's run sums.
+        packed = jax.lax.complex(w_all, w_frozen)
+        Wc = jnp.zeros((nv, nv), jnp.complex64).at[src, cd].add(packed)
+        W_all = jnp.real(Wc)       # true K_{i->c} per (vertex, community)
+        W_frz = jnp.imag(Wc)       # anchored K_{i->c}
 
-    # --- K_{i->d}: true weight to own community (excluding self) ---------
-    K_own = W_all[ids, C]
+        # --- K_{i->d}: true weight to own community (excluding self) ---------
+        K_own = W_all[ids, C]
 
-    # --- delta-modularity per candidate cell (paper Eq. 2) ---------------
-    Ki = K[:, None]
-    dq = (
-        2.0 * (W_all - K_own[:, None]) / two_m
-        - 2.0 * Ki * (Ki + Sigma[None, :] - Sigma[C][:, None]) / (two_m * two_m)
-    )
-    # A cell (i, c != C[i]) corresponds to a sortscan run iff some non-self
-    # edge i->j lands in c; all real edge weights are positive, so run
-    # existence is exactly W_all > 0 (and the anchored gate W_frz > 0
-    # subsumes it for cand).
-    geom = valid_cell & (c_ids != C[:, None])
-    cand = geom & (W_frz > 0.0) & movable[:, None]
-    if owned is not None:
-        cand = cand & owned[:, None]
-    if target_ok is not None:
-        cand = cand & target_ok[None, :]
-    want = jnp.max(jnp.where(geom & (W_all > 0.0), dq, NEG), axis=1) > 0.0
+        # --- delta-modularity per candidate cell (paper Eq. 2) ---------------
+        Ki = K[:, None]
+        dq = (
+            2.0 * (W_all - K_own[:, None]) / two_m
+            - 2.0 * Ki * (Ki + Sigma[None, :] - Sigma[C][:, None])
+            / (two_m * two_m)
+        )
+        # A cell (i, c != C[i]) corresponds to a sortscan run iff some non-self
+        # edge i->j lands in c; all real edge weights are positive, so run
+        # existence is exactly W_all > 0 (and the anchored gate W_frz > 0
+        # subsumes it for cand).
+        geom = valid_cell & (c_ids != C[:, None])
+        cand = geom & (W_frz > 0.0) & movable[:, None]
+        if owned is not None:
+            cand = cand & owned[:, None]
+        if target_ok is not None:
+            cand = cand & target_ok[None, :]
+        want = jnp.max(jnp.where(geom & (W_all > 0.0), dq, NEG), axis=1) > 0.0
 
-    # --- argmax per source vertex (min community id breaks ties) ---------
-    dq_cand = jnp.where(cand, dq, NEG)
-    best = jnp.max(dq_cand, axis=1)
-    c_star = jnp.min(
-        jnp.where(cand & (dq_cand >= best[:, None] - 0.0), c_ids, seg.INT_MAX),
-        axis=1,
-    )
-    move = (best > 0.0) & (c_star < ghost)
-    C_local = jnp.where(move, c_star.astype(jnp.int32), C)
+        # --- argmax per source vertex (min community id breaks ties) ---------
+        dq_cand = jnp.where(cand, dq, NEG)
+        best = jnp.max(dq_cand, axis=1)
+        c_star = jnp.min(
+            jnp.where(cand & (dq_cand >= best[:, None] - 0.0), c_ids,
+                      seg.INT_MAX),
+            axis=1,
+        )
+    with scope("move"):
+        move = (best > 0.0) & (c_star < ghost)
+        C_local = jnp.where(move, c_star.astype(jnp.int32), C)
 
-    # --- merge + exact Sigma recompute: identical to the sort path -------
-    if owned is None:
-        C_new = C_local.at[ghost].set(ghost)
-        moved = move
-        Sigma_new = jax.ops.segment_sum(K, C_new, num_segments=nv)
-        gain = jnp.sum(jnp.where(move, best, 0.0))
-    else:
-        C_new = col.psum(jnp.where(owned, C_local, 0), axis)
-        C_new = C_new.at[ghost].set(ghost)
-        moved = col.psum(
-            jnp.where(owned & move, 1, 0).astype(jnp.int32), axis) > 0
-        Sigma_new = jax.ops.segment_sum(K, C_new, num_segments=nv)
-        gain = col.psum(jnp.sum(jnp.where(owned & move, best, 0.0)), axis)
-        want = col.pmax((want & owned).astype(jnp.int32), axis) > 0
+        # --- merge + exact Sigma recompute: identical to the sort path -------
+        if owned is None:
+            C_new = C_local.at[ghost].set(ghost)
+            moved = move
+            Sigma_new = jax.ops.segment_sum(K, C_new, num_segments=nv)
+            gain = jnp.sum(jnp.where(move, best, 0.0))
+        else:
+            C_new = col.psum(jnp.where(owned, C_local, 0), axis)
+            C_new = C_new.at[ghost].set(ghost)
+            moved = col.psum(
+                jnp.where(owned & move, 1, 0).astype(jnp.int32), axis) > 0
+            Sigma_new = jax.ops.segment_sum(K, C_new, num_segments=nv)
+            gain = col.psum(jnp.sum(jnp.where(owned & move, best, 0.0)), axis)
+            want = col.pmax((want & owned).astype(jnp.int32), axis) > 0
     return C_new, Sigma_new, moved, gain, want
 
 

@@ -17,14 +17,14 @@ Split policies (``LouvainConfig.split``):
              masked graph; the greedy theta->0 variant (our Figure-4
              comparison baseline, "GVE-Leiden"-like).
 
-The staged driver (:func:`louvain_staged`) runs the same phases as separate
-jitted calls with host-side timing, reproducing the paper's Figure 5
-phase/pass split measurements.
+Each phase runs under its device scope (``repro.telemetry.spans.SCOPES``):
+``local_move``, ``split`` (``refine`` in that slot), ``renumber`` and
+``aggregate``, so a profiler trace splits device time by phase (the paper's
+Figure 5 phase split).
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import NamedTuple
 
@@ -37,6 +37,7 @@ from repro.core.local_move import local_move
 from repro.core.split import split_labels
 from repro.graph.container import Graph
 from repro.kernels import ops
+from repro.telemetry.spans import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,31 +143,36 @@ def louvain_impl(g: Graph, cfg: LouvainConfig = LouvainConfig(), *, axis=None,
         # the split fixpoint (dense scan only)
         adj = (jnp.zeros((nv, nv), bool).at[st.esrc, st.edst].set(True)
                if scan == "dense" else None)
-        C, _, li = local_move(
-            st.esrc, st.edst, st.ew, C0, K, K, two_m,
-            tau=st.tau, max_iters=cfg.max_iters, sync=cfg.sync,
-            prune=cfg.prune, axis=axis, owned=owned, scan=scan,
-            skip=st.done, adj=adj, seg_impl=seg_impl, block_m=block_m,
-        )
+        with scope("local_move"):
+            C, _, li = local_move(
+                st.esrc, st.edst, st.ew, C0, K, K, two_m,
+                tau=st.tau, max_iters=cfg.max_iters, sync=cfg.sync,
+                prune=cfg.prune, axis=axis, owned=owned, scan=scan,
+                skip=st.done, adj=adj, seg_impl=seg_impl, block_m=block_m,
+            )
         if cfg.split == "refine":
-            labels = refine_labels(
-                st.esrc, st.edst, st.ew, C, two_m,
-                tau=st.tau, max_iters=cfg.max_iters, axis=axis, owned=owned,
-                scan=scan, skip=st.done, seg_impl=seg_impl, block_m=block_m,
-            )
+            with scope("refine"):
+                labels = refine_labels(
+                    st.esrc, st.edst, st.ew, C, two_m,
+                    tau=st.tau, max_iters=cfg.max_iters, axis=axis,
+                    owned=owned, scan=scan, skip=st.done, seg_impl=seg_impl,
+                    block_m=block_m,
+                )
         elif do_sp:
-            labels, _ = split_labels(
-                st.esrc, st.edst, st.ew, C,
-                mode=mode, max_iters=cfg.split_max_iters, axis=axis,
-                impl=split_impl, skip=st.done, adj=adj, seg_impl=seg_impl,
-                block_m=block_m,
-            )
+            with scope("split"):
+                labels, _ = split_labels(
+                    st.esrc, st.edst, st.ew, C,
+                    mode=mode, max_iters=cfg.split_max_iters, axis=axis,
+                    impl=split_impl, skip=st.done, adj=adj,
+                    seg_impl=seg_impl, block_m=block_m,
+                )
         else:
             labels = C
         # split-pass trigger count: vertices the split/refine slot moved
         # out of their local-move community this pass (telemetry)
         moved = jnp.sum((labels != C) & node_valid).astype(jnp.int32)
-        C_dense, n_comms = seg.renumber(labels, node_valid, nv)
+        with scope("renumber"):
+            C_dense, n_comms = seg.renumber(labels, node_valid, nv)
         Ctop = C_dense[st.Ctop]
 
         converged = li <= 1
@@ -175,9 +181,10 @@ def louvain_impl(g: Graph, cfg: LouvainConfig = LouvainConfig(), *, axis=None,
         )
         done = converged | low_shrink
 
-        nsrc, ndst, nw = aggregate(st.esrc, st.edst, st.ew, C_dense,
-                                   impl=agg_impl, seg_impl=seg_impl,
-                                   block_m=block_m)
+        with scope("aggregate"):
+            nsrc, ndst, nw = aggregate(st.esrc, st.edst, st.ew, C_dense,
+                                       impl=agg_impl, seg_impl=seg_impl,
+                                       block_m=block_m)
         # freeze the graph if we're done (avoids dead aggregation writes)
         esrc = jnp.where(done, st.esrc, nsrc)
         edst = jnp.where(done, st.edst, ndst)
@@ -207,14 +214,16 @@ def louvain_impl(g: Graph, cfg: LouvainConfig = LouvainConfig(), *, axis=None,
     Ctop = out.Ctop
     split_moved = out.split_moved
     if cfg.split.startswith("sl"):
-        labels, _ = split_labels(
-            g.src, g.dst, g.w, Ctop, mode=mode,
-            max_iters=cfg.split_max_iters, axis=axis, impl=split_impl,
-            seg_impl=seg_impl, block_m=block_m,
-        )
+        with scope("split"):
+            labels, _ = split_labels(
+                g.src, g.dst, g.w, Ctop, mode=mode,
+                max_iters=cfg.split_max_iters, axis=axis, impl=split_impl,
+                seg_impl=seg_impl, block_m=block_m,
+            )
         split_moved = split_moved + jnp.sum(
             (labels != Ctop) & g.node_mask()).astype(jnp.int32)
-        Ctop, _ = seg.renumber(labels, g.node_mask(), nv)
+        with scope("renumber"):
+            Ctop, _ = seg.renumber(labels, g.node_mask(), nv)
     n_final = seg.count_communities(Ctop, g.node_mask(), nv)
     stats = dict(passes=out.lp, li_last=out.li_last,
                  li_total=out.li_total, split_moved=split_moved,
@@ -281,108 +290,3 @@ def louvain(g: Graph, cfg: LouvainConfig | None = None, *, options=None,
     scan = "sort" if opts.scan == "auto" else opts.scan
     return _louvain_jit(g, opts.louvain, axis=axis, owned=owned, scan=scan,
                         seg_impl=opts.seg_impl, block_m=opts.block_m)
-
-
-# --------------------------------------------------------------------------
-# Staged driver: same algorithm as a host loop over separately-jitted phases,
-# with wall-clock per phase — reproduces paper Figure 5 measurements.
-# --------------------------------------------------------------------------
-
-def _timed(fn, *args, **kw):
-    t0 = time.perf_counter()
-    out = fn(*args, **kw)
-    jax.block_until_ready(out)
-    return out, time.perf_counter() - t0
-
-
-def louvain_staged(g: Graph, cfg: LouvainConfig = LouvainConfig(), *,
-                   seg_impl: str = "auto", block_m: int = 0):
-    """Host-staged GSP-Louvain with per-phase / per-pass wall times.
-
-    Returns (C, stats) where stats carries ``phase_seconds`` =
-    {local_move, split, aggregate, other} and ``pass_seconds`` list.
-    ``seg_impl``/``block_m`` select the segment-reduction backend exactly
-    as in :func:`louvain_impl`.
-    """
-    nv = g.nv
-    two_m = g.total_weight_2m()
-    do_sp = cfg.split.startswith("sp")
-    mode = _split_mode(cfg.split)
-    seg_impl = ops.resolve_impl(seg_impl)
-
-    esrc, edst, ew = g.src, g.dst, g.w
-    Ctop = jnp.arange(nv, dtype=jnp.int32)
-    n_cur = int(g.n_nodes)
-    tau = float(cfg.tolerance)
-    phase = dict(local_move=0.0, split=0.0, aggregate=0.0, other=0.0)
-    pass_seconds = []
-    passes = 0
-    li = 0
-    li_total = 0
-    split_moved = 0
-
-    for _ in range(cfg.max_passes):
-        t_pass = time.perf_counter()
-        node_valid = jnp.arange(nv) < n_cur
-        (K,), t_o = _timed(
-            lambda: (jax.ops.segment_sum(ew, esrc, num_segments=nv),)
-        )
-        phase["other"] += t_o
-        C0 = jnp.arange(nv, dtype=jnp.int32)
-        (C, _, li_a), t_lm = _timed(
-            local_move, esrc, edst, ew, C0, K, K, two_m,
-            tau=tau, max_iters=cfg.max_iters, sync=cfg.sync, prune=cfg.prune,
-            seg_impl=seg_impl, block_m=block_m,
-        )
-        phase["local_move"] += t_lm
-        li = int(li_a)
-        if cfg.split == "refine":
-            (labels), t_sp = _timed(
-                refine_labels, esrc, edst, ew, C, two_m,
-                tau=tau, max_iters=cfg.max_iters, seg_impl=seg_impl,
-                block_m=block_m,
-            )
-            phase["split"] += t_sp
-        elif do_sp:
-            (labels, _), t_sp = _timed(
-                split_labels, esrc, edst, ew, C,
-                mode=mode, max_iters=cfg.split_max_iters, seg_impl=seg_impl,
-                block_m=block_m,
-            )
-            phase["split"] += t_sp
-        else:
-            labels = C
-        li_total += li
-        split_moved += int(jnp.sum((labels != C) & node_valid))
-        (res, t_o) = _timed(seg.renumber, labels, node_valid, nv)
-        C_dense, n_comms = res
-        phase["other"] += t_o
-        Ctop = C_dense[Ctop]
-        passes += 1
-        n_comms = int(n_comms)
-        pass_seconds.append(time.perf_counter() - t_pass)
-        if li <= 1 or n_comms > cfg.aggregation_tolerance * n_cur:
-            break
-        (agg, t_ag) = _timed(aggregate, esrc, edst, ew, C_dense,
-                             seg_impl=seg_impl, block_m=block_m)
-        esrc, edst, ew = agg
-        phase["aggregate"] += t_ag
-        n_cur = n_comms
-        tau /= cfg.tolerance_drop
-
-    if cfg.split.startswith("sl"):
-        (labels, _), t_sp = _timed(
-            split_labels, g.src, g.dst, g.w, Ctop,
-            mode=mode, max_iters=cfg.split_max_iters, seg_impl=seg_impl,
-            block_m=block_m,
-        )
-        phase["split"] += t_sp
-        split_moved += int(jnp.sum((labels != Ctop) & g.node_mask()))
-        Ctop, _ = seg.renumber(labels, g.node_mask(), nv)
-    n_final = int(seg.count_communities(Ctop, g.node_mask(), nv))
-    stats = dict(
-        passes=passes, li_last=li, li_total=li_total,
-        split_moved=split_moved, n_communities=n_final,
-        phase_seconds=phase, pass_seconds=pass_seconds,
-    )
-    return Ctop, stats

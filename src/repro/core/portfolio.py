@@ -33,6 +33,13 @@ degrade (resilience/degrade.py routes through this module too).
 Stats dicts are shape-uniform across tiers (passes / li_last / li_total /
 split_moved / n_communities, all int32 scalars) so the batched engine can
 swap algorithms per compile key without changing its unpacking.
+
+A detection runs three device programs, each under its scope
+(``repro.telemetry.spans.SCOPES``): ``partition``, ``detector`` and
+``modularity``.  :func:`run_detection` also opens the host annotations
+``repro.partition`` / ``repro.detector`` / ``repro.modularity`` around
+their dispatch and ``repro.fetch`` around the blocking reads of the
+results.
 """
 from __future__ import annotations
 
@@ -43,9 +50,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import _segments as seg
+from repro.core.detect import disconnected_communities_impl
 from repro.core.louvain import LouvainConfig, louvain_impl
 from repro.core.lpa import lpa_run
 from repro.core.modularity import modularity
+from repro.telemetry.spans import annotate, scope
 
 ALGORITHMS = ("fast", "standard", "max-quality")
 
@@ -147,11 +156,28 @@ def partition_impl(g, algorithm: str, cfg: LouvainConfig, *,
     return C, stats
 
 
-_partition_jit = partial(
-    jax.jit,
-    static_argnames=("algorithm", "cfg", "axis", "scan", "seg_impl",
-                     "block_m"),
-)(partition_impl)
+@partial(jax.jit, static_argnames=("algorithm", "cfg", "axis", "scan",
+                                   "seg_impl", "block_m"))
+def _partition_jit(g, algorithm, cfg, *, axis=None, owned=None,
+                   scan="sort", seg_impl="auto", block_m=0):
+    with scope("partition"):
+        return partition_impl(g, algorithm, cfg, axis=axis, owned=owned,
+                              scan=scan, seg_impl=seg_impl, block_m=block_m)
+
+
+@partial(jax.jit, static_argnames=("seg_impl", "block_m"))
+def _detector_jit(g, C, *, seg_impl, block_m):
+    with scope("detector"):
+        return disconnected_communities_impl(
+            g.src, g.dst, g.w, C, g.n_nodes, seg_impl=seg_impl,
+            block_m=block_m)
+
+
+@partial(jax.jit, static_argnames=("seg_impl", "block_m"))
+def _modularity_jit(g, C, *, seg_impl, block_m):
+    with scope("modularity"):
+        return modularity(g.src, g.dst, g.w, C, seg_impl=seg_impl,
+                          block_m=block_m)
 
 
 def partition(g, options, *, axis=None, owned=None, telemetry=None):
@@ -173,10 +199,27 @@ def partition(g, options, *, axis=None, owned=None, telemetry=None):
             g, tier_config(options.algorithm, options.louvain), mesh=mesh,
             seg_impl=options.seg_impl, block_m=options.block_m,
             telemetry=telemetry)
-    scan = "sort" if options.scan == "auto" else options.scan
-    return _partition_jit(g, options.algorithm, options.louvain, axis=axis,
-                          owned=owned, scan=scan, seg_impl=options.seg_impl,
-                          block_m=options.block_m)
+    return _partition_jit(g, axis=axis, owned=owned,
+                          **_partition_static(options))
+
+
+def _partition_static(options) -> dict:
+    """The static arguments of ``_partition_jit`` for ``options``."""
+    return dict(algorithm=options.algorithm, cfg=options.louvain,
+                scan="sort" if options.scan == "auto" else options.scan,
+                seg_impl=options.seg_impl, block_m=options.block_m)
+
+
+def detection_programs(graph, options):
+    """The three single-device programs of a detection of ``graph``, with
+    ``options`` resolved for it: ``partition(g)``, ``detector(g, C)`` and
+    ``modularity(g, C)``.  Each is a ``functools.partial`` of a jitted
+    function, so ``p.func.lower(*args, **p.keywords)`` compiles it alone.
+    """
+    run = options.replace(scan=options.resolved_scan(graph.nv, graph.m_cap))
+    kw = dict(seg_impl=run.resolved_seg_impl(), block_m=run.block_m)
+    return (partial(_partition_jit, **_partition_static(run)),
+            partial(_detector_jit, **kw), partial(_modularity_jit, **kw))
 
 
 def run_detection(graph, options, *, telemetry=None):
@@ -189,26 +232,23 @@ def run_detection(graph, options, *, telemetry=None):
     the contract is checked, not assumed — and reported for 'fast').
     """
     from repro.core.api import Detection
-    from repro.core.detect import disconnected_communities
 
-    mesh = options.resolved_mesh()
-    if mesh is None:
-        opts_run = options.replace(
-            scan=options.resolved_scan(graph.nv, graph.m_cap))
-        C, stats = partition(graph, opts_run, telemetry=telemetry)
-    else:
-        C, stats = partition(graph, options, telemetry=telemetry)
-    seg_impl = options.resolved_seg_impl()
-    det = disconnected_communities(
-        graph.src, graph.dst, graph.w, C, graph.n_nodes,
-        seg_impl=seg_impl, block_m=options.block_m)
-    q = modularity(graph.src, graph.dst, graph.w, C,
-                   seg_impl=seg_impl, block_m=options.block_m)
-    return Detection(
-        labels=C,
-        n_communities=int(stats["n_communities"]),
-        n_disconnected=int(det["n_disconnected"]),
-        modularity=float(q),
-        stats=dict(stats),
-        contract=contract_for(options.algorithm),
-    )
+    part, detector, modularity_of = detection_programs(graph, options)
+    with annotate("partition"):
+        if options.resolved_mesh() is None:
+            C, stats = part(graph)
+        else:
+            C, stats = partition(graph, options, telemetry=telemetry)
+    with annotate("detector"):
+        det = detector(graph, C)
+    with annotate("modularity"):
+        q = modularity_of(graph, C)
+    with annotate("fetch"):
+        return Detection(
+            labels=C,
+            n_communities=int(stats["n_communities"]),
+            n_disconnected=int(det["n_disconnected"]),
+            modularity=float(q),
+            stats=dict(stats),
+            contract=contract_for(options.algorithm),
+        )

@@ -36,6 +36,7 @@ from repro.kernels.segsum import (
 )
 from repro.kernels.spmm import bucket_spmm as _bucket_spmm_kernel
 from repro.kernels.onehot_segsum import onehot_segsum as _onehot_segsum_kernel
+from repro.telemetry.spans import scope
 
 
 # kernel block rows when the caller pins none (kernels/autotune.py tunes it)
@@ -111,9 +112,15 @@ def segreduce_sorted(values, ids, num_segments, *, op: str = "sum",
     ``BLOCK_GRANULE``s and down to the padded input; 0 = ``DEFAULT_BLOCK_M``
     (the service engine passes the per-bucket autotuned value —
     kernels/autotune.py).
-    All impls are bit-identical (in-order fold contract).
+    All impls are bit-identical (in-order fold contract).  Every impl
+    runs under the device scope ``segreduce``, whatever phase calls it.
     """
-    impl = resolve_impl(impl)
+    with scope("segreduce"):
+        return _segreduce_sorted(values, ids, num_segments, op,
+                                 resolve_impl(impl), block_m)
+
+
+def _segreduce_sorted(values, ids, num_segments, op, impl, block_m):
     if impl == "scatter":
         return ref.segreduce_sorted_ref(values, ids, num_segments, op=op,
                                         assume_sorted=False)

@@ -57,6 +57,7 @@ from repro.kernels import ops
 from repro.kernels.autotune import autotune_block_m
 from repro.service.buckets import Bucket, bucket_of, filler
 from repro.telemetry.sinks import Telemetry
+from repro.telemetry.spans import annotate, scope
 
 
 @dataclasses.dataclass
@@ -96,10 +97,14 @@ class DispatchInfo:
     Monotonic-clock stamps bracket the phases the front end turns into
     batch-level spans: ``compile`` = (t_call0, t_call1) on a cache miss
     (jit compiles lazily at the first call) and empty on a hit;
-    ``engine-dispatch`` = the call interval minus compile; ``device-sync``
-    = (t_call1, t_sync), the device->host conversion that blocks on the
-    async dispatch.  ``fill`` is the live fraction of the padded batch
-    (filler slots excluded) — the bucket fill-factor gauge.
+    ``engine-dispatch`` = (t_start, t_call1) minus compile, with ``stack``
+    = (t_stack0, t_stacked) inside it (filler, stacking, reshape and the
+    transfer); ``device-sync`` = (t_call1, t_sync), the device->host
+    conversion that blocks on the async dispatch; ``unpack`` = (t_sync,
+    t_unpacked), the per-result conversion and the engine's counters.
+    The engine opens the matching ``repro.<span>`` profiler annotations
+    around the same work.  ``fill`` is the live fraction of the padded
+    batch (filler slots excluded) — the bucket fill-factor gauge.
     """
 
     kind: str                    # "detect" | "update"
@@ -108,14 +113,29 @@ class DispatchInfo:
     capacity: int                # n_tiles * sub_batch (padded width)
     compile_hit: bool
     t_start: float               # dispatch entry (host prep begins)
+    t_stack0: float              # program looked up, stacking begins
+    t_stacked: float             # batch stacked and on the device
     t_call0: float               # jitted call begins
     t_call1: float               # jitted call returned (async dispatch)
     t_sync: float                # device->host conversion finished
+    t_unpacked: float            # per-result conversion + counters done
     algorithm: str = "standard"  # portfolio tier the batch ran
 
     @property
     def fill(self) -> float:
         return self.n / self.capacity if self.capacity else 0.0
+
+
+def _tiled(gb: Graph, n_tiles: int, b: int) -> Graph:
+    """A stacked ``[n_tiles * b, ...]`` batch laid out as ``[n_tiles, b,
+    ...]`` for the ``lax.map`` over vmapped tiles."""
+    return Graph(
+        src=gb.src.reshape(n_tiles, b, -1),
+        dst=gb.dst.reshape(n_tiles, b, -1),
+        w=gb.w.reshape(n_tiles, b, -1),
+        n_nodes=gb.n_nodes.reshape(n_tiles, b),
+        n_cap=gb.n_cap, m_cap=gb.m_cap,
+    )
 
 
 # (bucket-padded updated graph — vertex+edge rewrites applied, previous
@@ -269,15 +289,19 @@ class BatchedLouvainEngine:
         return blk
 
     def _one(self, g: Graph, scan: str, block_m: int, algorithm: str):
-        C, stats = partition_impl(g, algorithm, self.cfg, scan=scan,
-                                  seg_impl=self.seg_impl, block_m=block_m)
-        det = disconnected_communities_impl(
-            g.src, g.dst, g.w, C, g.n_nodes,
-            impl="dense" if scan == "dense" else "coo",
-            seg_impl=self.seg_impl, block_m=block_m,
-        )
-        q = modularity(g.src, g.dst, g.w, C, seg_impl=self.seg_impl,
-                       block_m=block_m)
+        with scope("partition"):
+            C, stats = partition_impl(g, algorithm, self.cfg, scan=scan,
+                                      seg_impl=self.seg_impl,
+                                      block_m=block_m)
+        with scope("detector"):
+            det = disconnected_communities_impl(
+                g.src, g.dst, g.w, C, g.n_nodes,
+                impl="dense" if scan == "dense" else "coo",
+                seg_impl=self.seg_impl, block_m=block_m,
+            )
+        with scope("modularity"):
+            q = modularity(g.src, g.dst, g.w, C, seg_impl=self.seg_impl,
+                           block_m=block_m)
         return dict(
             C=C,
             n_communities=stats["n_communities"],
@@ -413,48 +437,73 @@ class BatchedLouvainEngine:
         # would recompile constantly.  <= log2(batch) executables per
         # bucket, filler slots are cheap (they converge in one pass).
         n_tiles = 1 << (-(-n // b) - 1).bit_length()
-        if n_tiles * b > n:
-            graphs = graphs + [filler(bucket)] * (n_tiles * b - n)
-        gb = stack_graphs(graphs)
-        tiled = Graph(
-            src=gb.src.reshape(n_tiles, b, -1),
-            dst=gb.dst.reshape(n_tiles, b, -1),
-            w=gb.w.reshape(n_tiles, b, -1),
-            n_nodes=gb.n_nodes.reshape(n_tiles, b),
-            n_cap=gb.n_cap, m_cap=gb.m_cap,
-        )
-        hit = self._detect_key(bucket, n_tiles, alg) in self._compiled
-        fn = self.compiled_fn(bucket, n_tiles, alg)
-        t_call0 = time.perf_counter()
-        with self._profiled():
-            out = fn(tiled)
-            t_call1 = time.perf_counter()
-            flat = {k: np.asarray(v).reshape((n_tiles * b,) + v.shape[2:])
-                    for k, v in out.items()}
-        t_sync = time.perf_counter()
-        info = DispatchInfo(
-            kind="detect", bucket=bucket, n=n, capacity=n_tiles * b,
-            compile_hit=hit, t_start=t_start, t_call0=t_call0,
-            t_call1=t_call1, t_sync=t_sync, algorithm=alg)
+
+        def compiled():
+            hit = self._detect_key(bucket, n_tiles, alg) in self._compiled
+            return self.compiled_fn(bucket, n_tiles, alg), hit
+
+        def stack():
+            padded = graphs + [filler(bucket)] * (n_tiles * b - n)
+            return (_tiled(stack_graphs(padded), n_tiles, b),)
+
+        flat, hit, stamps = self._run(t_start, compiled, stack, n_tiles * b)
+        with annotate("unpack"):
+            info = DispatchInfo(
+                "detect", bucket, n, n_tiles * b, hit, *stamps,
+                t_unpacked=stamps[-1], algorithm=alg)
+            self._note_compile(info)
+            self._note_dispatch(info, flat, n)
+            contract = contract_for(alg)
+            results = [
+                DetectResult(
+                    C=flat["C"][i],
+                    n_communities=int(flat["n_communities"][i]),
+                    n_disconnected=int(flat["n_disconnected"][i]),
+                    fraction=float(flat["fraction"][i]),
+                    passes=int(flat["passes"][i]),
+                    q=float(flat["q"][i]),
+                    sweeps=int(flat["sweeps"][i]),
+                    split_moved=int(flat["split_moved"][i]),
+                    algorithm=alg,
+                    contract=contract,
+                )
+                for i in range(n)
+            ]
+            info.t_unpacked = time.perf_counter()
         self.last_detect_info = info
-        self._note_compile(info)
-        self._note_dispatch(info, flat, n)
-        contract = contract_for(alg)
-        return [
-            DetectResult(
-                C=flat["C"][i],
-                n_communities=int(flat["n_communities"][i]),
-                n_disconnected=int(flat["n_disconnected"][i]),
-                fraction=float(flat["fraction"][i]),
-                passes=int(flat["passes"][i]),
-                q=float(flat["q"][i]),
-                sweeps=int(flat["sweeps"][i]),
-                split_moved=int(flat["split_moved"][i]),
-                algorithm=alg,
-                contract=contract,
-            )
-            for i in range(n)
-        ]
+        return results
+
+    def _run(self, t_start: float, compiled, stack, width: int):
+        """One dispatch that entered at ``t_start``: ``compiled()`` gives
+        the jitted program and whether it was cached, ``stack()`` builds
+        the call's arguments on the device, the program runs them, and
+        the outputs come back to the host as ``[width, ...]`` numpy
+        arrays.  Each step runs under the profiler annotation of the span
+        it becomes (``engine-dispatch`` with ``stack`` inside, ``compile``
+        for the call on a cache miss, ``device-sync``).  Returns ``(flat,
+        hit, (t_start, t_stack0, t_stacked, t_call0, t_call1, t_sync))``.
+        """
+        with self._profiled(), contextlib.ExitStack() as phase:
+            phase.enter_context(annotate("engine-dispatch"))
+            fn, hit = compiled()
+            with annotate("stack"):
+                t_stack0 = time.perf_counter()
+                args = stack()
+                t_stacked = time.perf_counter()
+            t_call0 = time.perf_counter()
+            if not hit:
+                # jit compiles inside the first call: that is not dispatch
+                phase.close()
+                phase.enter_context(annotate("compile"))
+            out = fn(*args)
+            t_call1 = time.perf_counter()
+            phase.close()
+            with annotate("device-sync"):
+                flat = {k: np.asarray(v).reshape((width,) + v.shape[2:])
+                        for k, v in out.items()}
+                t_sync = time.perf_counter()
+        return flat, hit, (t_start, t_stack0, t_stacked, t_call0, t_call1,
+                           t_sync)
 
     def detect_one(self, g: Graph, *,
                    algorithm: Optional[str] = None) -> DetectResult:
@@ -509,8 +558,10 @@ class BatchedLouvainEngine:
         t_sync = time.perf_counter()
         info = DispatchInfo(
             kind="detect", bucket=bucket_of(g), n=1,
-            capacity=1, compile_hit=True, t_start=t_start, t_call0=t_start,
-            t_call1=t_call1, t_sync=t_sync, algorithm=alg)
+            capacity=1, compile_hit=True, t_start=t_start,
+            t_stack0=t_start, t_stacked=t_start, t_call0=t_start,
+            t_call1=t_call1, t_sync=t_sync, t_unpacked=t_sync,
+            algorithm=alg)
         self.last_detect_info = info
         return DetectResult(
             C=np.asarray(C),
@@ -554,52 +605,47 @@ class BatchedLouvainEngine:
         b = self.sub_batch
         n = len(items)
         n_tiles = 1 << (-(-n // b) - 1).bit_length()
-        if n_tiles * b > n:
-            items = items + [self._filler_update(bucket)] * (n_tiles * b - n)
-        gb = stack_graphs([g for g, _, _ in items])
-        nv = bucket.nv
-        Cb = jnp.asarray(np.stack([np.asarray(C, np.int32)
-                                   for _, C, _ in items]))
-        Tb = jnp.asarray(np.stack([np.asarray(t, bool)
-                                   for _, _, t in items]))
-        tiled_g = Graph(
-            src=gb.src.reshape(n_tiles, b, -1),
-            dst=gb.dst.reshape(n_tiles, b, -1),
-            w=gb.w.reshape(n_tiles, b, -1),
-            n_nodes=gb.n_nodes.reshape(n_tiles, b),
-            n_cap=gb.n_cap, m_cap=gb.m_cap,
-        )
-        hit = self._update_key(bucket, n_tiles, tau, max_iters) \
-            in self._compiled
-        fn = self.update_fn(bucket, n_tiles, tau=tau, max_iters=max_iters)
-        t_call0 = time.perf_counter()
-        with self._profiled():
-            out = fn(tiled_g, Cb.reshape(n_tiles, b, nv),
-                     Tb.reshape(n_tiles, b, nv))
-            t_call1 = time.perf_counter()
-            flat = {k: np.asarray(v).reshape((n_tiles * b,) + v.shape[2:])
-                    for k, v in out.items()}
-        t_sync = time.perf_counter()
-        info = DispatchInfo(
-            kind="update", bucket=bucket, n=n, capacity=n_tiles * b,
-            compile_hit=hit, t_start=t_start, t_call0=t_call0,
-            t_call1=t_call1, t_sync=t_sync)
+
+        def compiled():
+            hit = self._update_key(bucket, n_tiles, tau, max_iters) \
+                in self._compiled
+            return self.update_fn(bucket, n_tiles, tau=tau,
+                                  max_iters=max_iters), hit
+
+        def stack():
+            padded = items + ([self._filler_update(bucket)]
+                              * (n_tiles * b - n))
+            nv = bucket.nv
+            Cb = jnp.asarray(np.stack([np.asarray(C, np.int32)
+                                       for _, C, _ in padded]))
+            Tb = jnp.asarray(np.stack([np.asarray(t, bool)
+                                       for _, _, t in padded]))
+            return (_tiled(stack_graphs([g for g, _, _ in padded]),
+                           n_tiles, b),
+                    Cb.reshape(n_tiles, b, nv), Tb.reshape(n_tiles, b, nv))
+
+        flat, hit, stamps = self._run(t_start, compiled, stack, n_tiles * b)
+        with annotate("unpack"):
+            info = DispatchInfo("update", bucket, n, n_tiles * b, hit,
+                                *stamps, t_unpacked=stamps[-1])
+            self._note_compile(info)
+            self._note_dispatch(info, flat, n)
+            results = [
+                UpdateResult(
+                    C=flat["C"][i],
+                    n_communities=int(flat["n_communities"][i]),
+                    n_disconnected=int(flat["n_disconnected"][i]),
+                    fraction=float(flat["fraction"][i]),
+                    iterations=int(flat["iterations"][i]),
+                    q=float(flat["q"][i]),
+                    n_affected=int(flat["n_affected"][i]),
+                    split_moved=int(flat["split_moved"][i]),
+                )
+                for i in range(n)
+            ]
+            info.t_unpacked = time.perf_counter()
         self.last_update_info = info
-        self._note_compile(info)
-        self._note_dispatch(info, flat, n)
-        return [
-            UpdateResult(
-                C=flat["C"][i],
-                n_communities=int(flat["n_communities"][i]),
-                n_disconnected=int(flat["n_disconnected"][i]),
-                fraction=float(flat["fraction"][i]),
-                iterations=int(flat["iterations"][i]),
-                q=float(flat["q"][i]),
-                n_affected=int(flat["n_affected"][i]),
-                split_moved=int(flat["split_moved"][i]),
-            )
-            for i in range(n)
-        ]
+        return results
 
     def _filler_update(self, bucket: Bucket) -> UpdateItem:
         """Bucket-shaped no-op update padding a partial batch: the filler

@@ -71,7 +71,7 @@ from repro.service.store import (
 )
 from repro.telemetry.prometheus import MetricsExporter
 from repro.telemetry.sinks import InMemorySink, JsonlSink, Telemetry
-from repro.telemetry.spans import RequestTrace
+from repro.telemetry.spans import RequestTrace, annotate
 from repro.timeline.tracker import (
     TimelineConfig, TimelineManager, translate_window,
 )
@@ -284,9 +284,10 @@ class ServiceFrontend:
         rid = f"d{next(self._seq)}-{graph_id}"
         trace = RequestTrace(rid, tenant=tenant, kind="detect",
                              clock=self.clock)
-        t_r0 = self.clock()
-        padded, bucket = admit(graph, self.config.buckets)
-        t_r1 = self.clock()
+        with annotate("repad"):
+            t_r0 = self.clock()
+            padded, bucket = admit(graph, self.config.buckets)
+            t_r1 = self.clock()
         trace.mark("submit", t0, t_r0)
         trace.mark("repad", t_r0, t_r1)
         fut = DetectionFuture(rid, tenant, graph_id, "detect", t0,
@@ -524,9 +525,10 @@ class ServiceFrontend:
             got = 0
             for bucket, alg in self.admission.ready_groups(self.clock(),
                                                            force=force):
-                t_c0 = self.clock()
-                reqs = self.admission.compose(bucket, algorithm=alg)
-                t_c1 = self.clock()
+                with annotate("drr-compose"):
+                    t_c0 = self.clock()
+                    reqs = self.admission.compose(bucket, algorithm=alg)
+                    t_c1 = self.clock()
                 if reqs:
                     for r in reqs:
                         tr = r.future.trace if r.future is not None else None
@@ -684,21 +686,22 @@ class ServiceFrontend:
             tr = req.future.trace if req.future is not None else None
             if tr is not None and info is not None:
                 _mark_engine_spans(tr, info)
-            t_s0 = self.clock()
             try:
-                entry = res_mgr.commit(partial(
-                    self.store.put,
-                    req.graph_id, req.graph, res.C,
-                    n_communities=res.n_communities,
-                    n_disconnected=res.n_disconnected, q=res.q,
-                    algorithm=alg,
-                ))
+                with annotate("store-commit"):
+                    t_s0 = self.clock()
+                    entry = res_mgr.commit(partial(
+                        self.store.put,
+                        req.graph_id, req.graph, res.C,
+                        n_communities=res.n_communities,
+                        n_disconnected=res.n_disconnected, q=res.q,
+                        algorithm=alg,
+                    ))
+                    t_s1 = self.clock()
             except Exception as e:
                 # commit failed after retries: this one request degrades
                 # (stale = the previous committed entry) or fails alone
                 served += self._shed(bucket, [req], e)
                 continue
-            t_s1 = self.clock()
             self.metrics.observe("detect", now - req.t_submit, now,
                                  tenant=req.tenant)
             self.metrics.edges_processed += float(live_edges(req.graph))
@@ -732,9 +735,10 @@ class ServiceFrontend:
             try:
                 if entry is None:   # evicted/expired since submit
                     raise KeyError(gid)
-                t_p0 = self.clock()
-                plans.append(self.store.prepare_update_seq(gid, batches))
-                t_p1 = self.clock()
+                with annotate("repad"):
+                    t_p0 = self.clock()
+                    plans.append(self.store.prepare_update_seq(gid, batches))
+                    t_p1 = self.clock()
                 for r in rs:
                     if r.future.trace is not None:
                         r.future.trace.mark("repad", t_p0, t_p1)
@@ -805,12 +809,14 @@ class ServiceFrontend:
             now = self.clock()
             for i, res in zip(idxs, results):
                 plan = plans[i]
-                t_s0 = self.clock()
                 try:
-                    entry = self.resilience.commit(partial(
-                        self.store.commit_update,
-                        plan, C=res.C, n_communities=res.n_communities,
-                        n_disconnected=res.n_disconnected, q=res.q))
+                    with annotate("store-commit"):
+                        t_s0 = self.clock()
+                        entry = self.resilience.commit(partial(
+                            self.store.commit_update,
+                            plan, C=res.C, n_communities=res.n_communities,
+                            n_disconnected=res.n_disconnected, q=res.q))
+                        t_s1 = self.clock()
                 except Exception as e:
                     # a failed commit fails THIS plan's futures only; the
                     # rest of the batch still resolves
@@ -818,7 +824,6 @@ class ServiceFrontend:
                         self.metrics.fail(r.tenant)
                         r.future.set_exception(e)
                     continue
-                t_s1 = self.clock()
                 if entry is None:
                     # the entry moved on (evicted/re-detected) while the
                     # batch computed; the stale write was dropped — fail
@@ -1118,15 +1123,19 @@ def _t_enqueued(trace: RequestTrace, fallback: float) -> float:
 def _mark_engine_spans(trace: RequestTrace, info: DispatchInfo):
     """Stamp one dispatch's batch-level phases onto a member request's
     trace: compile (empty interval on a cache hit), engine-dispatch
-    (host prep + traced jax call), device-sync (device->host blocking
-    conversion).  Every request in the batch shares these intervals."""
+    (host prep + traced jax call) with stack (host prep + transfer)
+    inside it, device-sync (device->host blocking conversion), unpack
+    (per-result conversion + engine counters).  Every request in the
+    batch shares these intervals."""
     hit = info.compile_hit
     trace.mark("compile", info.t_call0,
                info.t_call0 if hit else info.t_call1,
                hit="true" if hit else "false")
     trace.mark("engine-dispatch", info.t_start,
                info.t_call1 if hit else info.t_call0)
+    trace.mark("stack", info.t_stack0, info.t_stacked)
     trace.mark("device-sync", info.t_call1, info.t_sync)
+    trace.mark("unpack", info.t_sync, info.t_unpacked)
 
 
 def _graph_with_updates(g: Graph, batches) -> Graph:

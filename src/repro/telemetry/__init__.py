@@ -3,7 +3,8 @@
 Four layers (see README "Observability"):
 
 * :mod:`repro.telemetry.spans` — per-request lifecycle traces
-  (``submit -> ... -> resolve``) with monotonic-clock spans.
+  (``submit -> ... -> resolve``) with monotonic-clock spans, their
+  ``repro.*`` profiler annotations, and the device scope names.
 * :mod:`repro.telemetry.sinks` — the :class:`Telemetry` hub plus
   pluggable :class:`MetricSink` callbacks (in-memory aggregation, JSONL
   event log, custom).
@@ -20,7 +21,7 @@ from repro.telemetry.sinks import (
     InMemorySink, JsonlSink, MetricSink, Telemetry,
 )
 from repro.telemetry.spans import (
-    PHASES, RequestTrace, Span, phase_group,
+    PHASES, SCOPES, RequestTrace, Span, annotate, phase_group, scope,
 )
 
 __all__ = [
@@ -28,5 +29,6 @@ __all__ = [
     "MetricsExporter", "metric_names", "parse_prometheus",
     "render_prometheus",
     "InMemorySink", "JsonlSink", "MetricSink", "Telemetry",
-    "PHASES", "RequestTrace", "Span", "phase_group",
+    "PHASES", "SCOPES", "RequestTrace", "Span", "annotate", "phase_group",
+    "scope",
 ]

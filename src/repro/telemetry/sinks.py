@@ -209,10 +209,11 @@ class InMemorySink(MetricSink):
     def phase_breakdown(self) -> Dict[str, float]:
         """queue / engine / host share of total per-request span time
         (fractions summing to 1.0 when any spans were recorded)."""
-        from repro.telemetry.spans import phase_group
+        from repro.telemetry.spans import NESTED, phase_group
         totals = {"queue": 0.0, "engine": 0.0, "host": 0.0}
         for phase, h in self.phase_durations().items():
-            totals[phase_group(phase)] += h.sum
+            if phase not in NESTED:     # counted in its enclosing phase
+                totals[phase_group(phase)] += h.sum
         grand = sum(totals.values())
         if grand <= 0.0:
             return {k: 0.0 for k in totals}

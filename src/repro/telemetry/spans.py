@@ -3,8 +3,8 @@
 A request through the service passes a fixed set of phases::
 
     submit -> admission -> queue-wait -> drr-compose -> repad ->
-    compile(hit/miss) -> engine-dispatch -> device-sync ->
-    store-commit -> resolve
+    compile(hit/miss) -> engine-dispatch (stack inside) -> device-sync ->
+    unpack -> store-commit -> resolve
 
 Each phase is recorded as a :class:`Span` — a name plus monotonic-clock
 ``(t_start, t_end)`` — inside the request's :class:`RequestTrace`.  The
@@ -23,6 +23,15 @@ number that matters for per-phase latency attribution.
 Spans carry optional string labels (e.g. ``compile`` marks
 ``hit="true"|"false"``).  Completed traces are broadcast to the
 telemetry hub (:mod:`repro.telemetry.sinks`) at resolve time.
+
+One naming scheme serves the host and the device.  A span timed with
+:meth:`RequestTrace.span`, and every batch-level span where the engine
+and front end do its work, also opens the profiler annotation
+``repro.<name>`` (:func:`annotate`; a no-op while no profiler trace is
+active), so a ``jax.profiler`` trace shows the program's phases on the
+profiler's own clock.  Inside the detection programs, :func:`scope` names
+each phase with ``jax.named_scope``; the names are :data:`SCOPES`, and
+the compiled operations carry them as components of their op-name path.
 """
 from __future__ import annotations
 
@@ -30,6 +39,8 @@ import contextlib
 import dataclasses
 import time
 from typing import Callable, Dict, List, Optional
+
+import jax
 
 # canonical phase taxonomy, in lifecycle order (docs + tests key off this)
 PHASES = (
@@ -40,23 +51,65 @@ PHASES = (
     "repad",           # bucket padding (inside submit on the detect path)
     "compile",         # jit cache consult; labels: hit=true|false
     "engine-dispatch", # traced jax dispatch (host -> device)
+    "stack",           # filler, stack, reshape, transfer (in engine-dispatch)
     "device-sync",     # device -> host transfer + np conversion
+    "unpack",          # per-result conversion + engine counters
     "store-commit",    # versioned store write
     "resolve",         # future resolution fan-out
 )
+
+# phases that lie inside another phase's interval, so totals skip them
+NESTED: Dict[str, str] = {"stack": "engine-dispatch"}
+
+# device scopes of the detection programs (jax.named_scope), outermost
+# first: the three programs of one detection, the phases of a pass, the
+# steps of a local-move half-sweep, and every sorted segment reduction
+SCOPES = (
+    "partition",       # the multi-pass loop (every tier)
+    "local_move",      # local-moving phase of a pass
+    "sort",            # half-sweep: sort by (src, C[dst]) + payload gathers
+    "gain",            # half-sweep: run sums, Eq.-2 gains, best target
+    "move",            # half-sweep: apply moves, merge, recompute Sigma
+    "split",           # split slot: connected-component labels
+    "refine",          # split slot under 'refine' (Leiden refinement)
+    "renumber",        # dense renumbering of the slot's labels
+    "aggregate",       # super-graph construction
+    "detector",        # disconnected-community detector
+    "modularity",      # modularity of the returned partition
+    "segreduce",       # kernels/ops.segreduce_sorted, in any phase
+)
+
+# prefix of the program's profiler annotations
+ANNOTATION_PREFIX = "repro."
 
 # phases grouped for the replay harness's breakdown report
 PHASE_GROUPS: Dict[str, str] = {
     "queue-wait": "queue",
     "compile": "engine",
     "engine-dispatch": "engine",
+    "stack": "engine",
     "device-sync": "engine",
+    "unpack": "engine",
 }
 
 
 def phase_group(name: str) -> str:
     """queue / engine / host bucket for a span name."""
     return PHASE_GROUPS.get(name, "host")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of :data:`SCOPES`, opened where a
+    phase is called, so the phase's operations carry the name."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown scope {name!r}; add it to SCOPES")
+    return jax.named_scope(name)
+
+
+def annotate(name: str):
+    """The profiler annotation ``repro.<name>`` (does nothing while no
+    profiler trace is active)."""
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
 
 
 @dataclasses.dataclass
@@ -107,12 +160,14 @@ class RequestTrace:
 
     @contextlib.contextmanager
     def span(self, name: str, **labels: str):
-        """Context-manager phase: ``with trace.span("repad"): ...``."""
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            self.mark(name, t0, self.clock(), **labels)
+        """Context-manager phase: ``with trace.span("repad"): ...``; also
+        the profiler annotation ``repro.<name>`` while it runs."""
+        with annotate(name):
+            t0 = self.clock()
+            try:
+                yield
+            finally:
+                self.mark(name, t0, self.clock(), **labels)
 
     def durations(self) -> Dict[str, float]:
         """Total seconds per phase name (a repeated phase accumulates)."""

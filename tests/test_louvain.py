@@ -7,7 +7,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import (
-    LouvainConfig, louvain, louvain_staged, modularity,
+    LouvainConfig, louvain, modularity,
     disconnected_communities, split_labels, aggregate,
 )
 from repro.core import _segments as seg
@@ -179,17 +179,6 @@ def test_renumber_dense():
     assert set(d) == {0, 1, 2, 3}
     # same label -> same dense id
     assert d[0] == d[1] and d[2] == d[4]
-
-
-def test_staged_matches_fused():
-    g = sbm_graph(120, 4, seed=5)[0]
-    C1, _ = louvain(g, LouvainConfig())
-    C2, stats = louvain_staged(g, LouvainConfig())
-    q1 = float(modularity(g.src, g.dst, g.w, C1))
-    q2 = float(modularity(g.src, g.dst, g.w, C2))
-    assert q1 == pytest.approx(q2, abs=1e-5)
-    assert set(stats["phase_seconds"]) == {
-        "local_move", "split", "aggregate", "other"}
 
 
 def test_sync_ablations_run():

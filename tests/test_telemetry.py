@@ -28,11 +28,15 @@ pytestmark = pytest.mark.service
 CFG = LouvainConfig()
 BUCKETS = (Bucket(64, 512), Bucket(64, 2048), Bucket(256, 2048))
 
-# the three request shapes and the spans each must carry end to end
+# the three request shapes and the spans each must carry end to end; the
+# engine's host spans (stack, unpack) come with every batched dispatch
+ENGINE_HOST_PHASES = {"stack", "unpack"}
 DETECT_PHASES = set(PHASES)
 IMMEDIATE_UPDATE_PHASES = {"submit", "repad", "compile", "engine-dispatch",
                            "device-sync", "store-commit", "resolve"}
 BATCHED_UPDATE_PHASES = (DETECT_PHASES - {"admission"})
+assert ENGINE_HOST_PHASES <= DETECT_PHASES & BATCHED_UPDATE_PHASES
+assert not ENGINE_HOST_PHASES & IMMEDIATE_UPDATE_PHASES
 
 
 def _ego(seed, n=30):
@@ -239,6 +243,20 @@ def test_exporter_live_http_scrape():
 # trace completeness + parity across the three front ends
 # ---------------------------------------------------------------------------
 
+def test_phase_breakdown_counts_a_nested_span_once():
+    sink = InMemorySink()
+    tel = Telemetry()
+    tel.register(sink)
+    tr = RequestTrace("d0-g")
+    tr.mark("queue-wait", 0.0, 1.0)
+    tr.mark("engine-dispatch", 1.0, 3.0)
+    tr.mark("stack", 1.0, 2.0)          # inside engine-dispatch
+    tr.mark("unpack", 3.0, 4.0)
+    tel.trace(tr)
+    assert sink.phase_breakdown() == pytest.approx(
+        {"queue": 0.25, "engine": 0.75, "host": 0.0})
+
+
 def test_sync_adapter_detect_trace_is_complete():
     svc = CommunityService(CFG, buckets=BUCKETS, batch_size=2,
                            max_delay_s=0.01)
@@ -253,6 +271,14 @@ def test_sync_adapter_detect_trace_is_complete():
     order = [s.name for s in fut.trace.spans]
     assert order.index("submit") < order.index("queue-wait") \
         < order.index("engine-dispatch") < order.index("resolve")
+    # stack lies inside engine-dispatch; unpack runs from the end of
+    # device-sync to before the store commit
+    (disp,), (stack,) = fut.trace.find("engine-dispatch"), \
+        fut.trace.find("stack")
+    assert disp.t_start <= stack.t_start < stack.t_end <= disp.t_end
+    (sync,), (unpack,), (commit,) = (fut.trace.find(n) for n in (
+        "device-sync", "unpack", "store-commit"))
+    assert unpack.t_start == sync.t_end and unpack.t_end <= commit.t_start
 
 
 def test_frontend_and_async_traces_match_sync(tmp_path):
