@@ -42,6 +42,14 @@ from repro.telemetry.spans import scope
 # kernel block rows when the caller pins none (kernels/autotune.py tunes it)
 DEFAULT_BLOCK_M = 8192
 
+# Cost of the end-mark placement per row, in rows gathered by one step of
+# the boundary search: segreduce_sorted places by mark where
+# num_segments * ceil(log2(m + 1)) > ENDS_MARK_RATIO * m.  Measured on a
+# TPU v5e (scripts/ends_crossover.py): the search won where that product
+# was at most 0.44 m, the mark where it was 0.74 m or more; the constant is
+# their geometric mean.
+ENDS_MARK_RATIO = 0.57
+
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
@@ -114,6 +122,9 @@ def segreduce_sorted(values, ids, num_segments, *, op: str = "sum",
     kernels/autotune.py).
     All impls are bit-identical (in-order fold contract).  Every impl
     runs under the device scope ``segreduce``, whatever phase calls it.
+    The Pallas path then places each segment's fold, from the static
+    shapes, by run-end mark (scope ``ends_mark``) or by binary search for
+    the segment's end (``ends_search``): see ``ENDS_MARK_RATIO``.
     """
     with scope("segreduce"):
         return _segreduce_sorted(values, ids, num_segments, op,
@@ -143,14 +154,52 @@ def _segreduce_sorted(values, ids, num_segments, op, impl, block_m):
         v = jnp.concatenate([v, jnp.full((pad, v.shape[1]), ident, v.dtype)])
         starts = jnp.concatenate([starts, jnp.ones((pad,), jnp.int32)])
     scanned = segscan_blocked(v, starts, op=op, block_m=block_m)[:m]
-    # boundary gather: the running value at a segment's last element IS the
-    # segment's in-order fold; searchsorted finds it without any scatter
+    # the running value at a segment's last row IS the segment's in-order
+    # fold: place it with whichever finding of the last rows costs less
+    if num_segments * m.bit_length() > ENDS_MARK_RATIO * m:
+        with scope("ends_mark"):
+            out = _ends_mark(ids, scanned, num_segments, ident)
+    else:
+        with scope("ends_search"):
+            out = _ends_search(ids, scanned, num_segments, ident)
+    return out[:, 0] if squeeze else out
+
+
+def _ends_mark(ids, scanned, num_segments, ident):
+    """Place the scan at each row that ends a run into its segment's slot
+    of an ``ident``-filled ``[num_segments, D]`` buffer: one scatter of m
+    values (a single channel), or of m row indices and then a gather of
+    the rows (several channels)."""
+    m, d = scanned.shape
+    last = jnp.concatenate([ids[1:] != ids[:-1], jnp.ones((1,), bool)])
+    # every other row goes to a slot of its own past the end, so the
+    # indices are unique and those writes are dropped
+    dest = jnp.where(last, ids,
+                     num_segments + jnp.arange(m, dtype=ids.dtype))
+
+    def place(fill, x):
+        return jnp.full((num_segments,), fill, x.dtype).at[dest].set(
+            x, mode="drop", unique_indices=True, wrap_negative_indices=False)
+
+    if d == 1:
+        return place(ident, scanned[:, 0])[:, None]
+    # rows of several values scatter slowly: on a TPU v5e, 882,692 rows of
+    # two took 41.9 ms, their row indices 6.1 ms and the gather 5.4 ms
+    ends = place(m, jnp.arange(m, dtype=jnp.int32))
+    return jnp.where((ends < m)[:, None], scanned[jnp.minimum(ends, m - 1)],
+                     ident)
+
+
+def _ends_search(ids, scanned, num_segments, ident):
+    """Find each segment's last row by binary search over the sorted ids
+    and gather the scan there: ``ceil(log2(m + 1))`` dependent gathers of
+    ``num_segments`` rows."""
+    m = ids.shape[0]
     seg = jnp.arange(num_segments, dtype=ids.dtype)
     ends = jnp.searchsorted(ids, seg, side="right").astype(jnp.int32) - 1
     present = (ends >= 0) & (ids[jnp.clip(ends, 0, m - 1)] == seg)
-    out = jnp.where(present[:, None],
-                    scanned[jnp.clip(ends, 0, m - 1)], ident)
-    return out[:, 0] if squeeze else out
+    return jnp.where(present[:, None],
+                     scanned[jnp.clip(ends, 0, m - 1)], ident)
 
 
 def spmm(nbr, w, x, *, impl: str = "auto", block_n: int = 64):

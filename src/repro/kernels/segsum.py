@@ -2,9 +2,9 @@
 
 The paper's hot loops (local-move scoring, aggregation, LP label-min) are
 all reduce-by-key over *sorted* runs.  A segment reduction over sorted
-ids is a streaming **blocked scan** with a carry, then one
-O(1)-per-segment gather of the scan output at run boundaries
-(``ops.segreduce_sorted``), with no scatter anywhere.
+ids is a streaming **blocked scan** with a carry, then the scan output at
+each run's last row placed in its segment's slot
+(``ops.segreduce_sorted``).
 
 Two scan kernels live here:
 

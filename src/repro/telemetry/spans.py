@@ -77,6 +77,8 @@ SCOPES = (
     "detector",        # disconnected-community detector
     "modularity",      # modularity of the returned partition
     "segreduce",       # kernels/ops.segreduce_sorted, in any phase
+    "ends_mark",       # segreduce: segment folds placed from run-end marks
+    "ends_search",     # segreduce: segment ends found by binary search
 )
 
 # prefix of the program's profiler annotations

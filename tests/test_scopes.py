@@ -4,6 +4,7 @@ profiler trace on its clock."""
 import glob
 import json
 import os
+import pathlib
 import re
 import sys
 import time
@@ -95,6 +96,29 @@ def test_sorted_reductions_carry_segreduce_in_every_phase():
                        "modularity")}
     assert phases == {"local_move", "split", "aggregate", "detector",
                       "modularity"}
+
+
+def test_sort_scan_places_pass_a_and_aggregation_folds_by_end_mark():
+    # local move's pass A and the aggregation reduce over as many run ids
+    # as entries: their folds are placed from run-end marks, inside the
+    # segreduce scope (the Pallas path; 'xla' has no placement step)
+    g = _graph()
+    part = detection_programs(g, DetectOptions(scan="sort",
+                                               seg_impl="pallas"))[0]
+    paths = _paths(part.func.lower(g, **part.keywords).compile().as_text())
+    marked = [set(p) for p in paths if "ends_mark" in p]
+    assert any({"local_move", "gain"} <= p for p in marked)
+    assert any("aggregate" in p for p in marked)
+    assert all("segreduce" in p for p in paths
+               if {"ends_mark", "ends_search"} & set(p))
+
+
+def test_scopes_hold_every_name_a_call_site_opens():
+    src = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro"
+    opened = {n for f in src.rglob("*.py")
+              for n in re.findall(r'\bscope\("(\w+)"\)', f.read_text())}
+    assert {"segreduce", "ends_mark", "ends_search"} <= opened
+    assert opened <= set(SCOPES)
 
 
 def test_engine_detect_and_update_programs_carry_the_scopes():

@@ -21,7 +21,7 @@ from repro.core import (
 from repro.core import _segments as seg
 from repro.core.local_move import _half_sweep, _half_sweep_scatter
 from repro.core.modularity import modularity
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.kernels.segsum import _default_interpret, segscan_blocked
 from repro.graph import (
     grid_graph, ring_of_cliques, rmat_graph, sbm_graph,
@@ -82,6 +82,95 @@ def test_segreduce_empty_and_tail_segments():
                                           impl="pallas", block_m=2))
     assert out[0] == -np.inf and out[9] == -np.inf  # empty-segment fill
     assert out[3] == 4.0 and out[7] == 5.0
+
+
+# the two placements of the folds (kernels/ops.py): the end mark where
+# the segments are about as many as the rows, the boundary search where
+# they are few; m = 1500 is no whole number of kernel blocks
+ENDS_LAYOUTS = {
+    # pass A's dense run ids, num_segments = m
+    "run_ids": ("mark", 1500, 1500),
+    # sparse sorted ids with empty head, middle and tail segments
+    "sparse_many": ("mark", 1500, 3000),
+    "sparse_few": ("search", 1500, 12),
+}
+DTYPES = (np.float32, np.int32, np.uint32)
+
+
+def _ends_layout(kind, rng):
+    side, m, nseg = ENDS_LAYOUTS[kind]
+    by_mark = nseg * m.bit_length() > ops.ENDS_MARK_RATIO * m
+    assert by_mark == (side == "mark"), kind
+    if kind == "run_ids":
+        starts = rng.random(m) < 0.6
+        starts[0] = True
+        return np.cumsum(starts).astype(np.int32) - 1, nseg
+    # segments 0-1 and the last two stay empty, and so does every third
+    # segment between them
+    used = np.array([s for s in range(2, nseg - 2) if s % 3])
+    return np.sort(rng.choice(used, m)).astype(np.int32), nseg
+
+
+def _ends_values(dtype, shape, rng):
+    if dtype == np.float32:
+        return rng.normal(size=shape).astype(dtype)
+    info = np.iinfo(dtype)
+    return rng.integers(info.min, info.max, shape, dtype=dtype,
+                        endpoint=True)
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda t: t.__name__)
+@pytest.mark.parametrize("op", ["sum", "max", "min"])
+@pytest.mark.parametrize("kind", list(ENDS_LAYOUTS))
+def test_segreduce_parity_on_both_sides_of_the_ends_rule(kind, op, dtype):
+    """Both placements, every op, D 1 and 2, float and (wrapping) integer
+    payloads: pallas == xla == scatter, bit for bit."""
+    rng = np.random.default_rng(sum(map(ord, kind + op)))
+    ids, nseg = _ends_layout(kind, rng)
+    for shape in ((ids.size,), (ids.size, 2)):
+        v = jnp.asarray(_ends_values(dtype, shape, rng))
+        _assert_all_impls_equal(v, jnp.asarray(ids), nseg, op, 1024)
+
+
+@pytest.mark.parametrize("op", ["sum", "max", "min"])
+@pytest.mark.parametrize("kind", list(ENDS_LAYOUTS))
+def test_segreduce_parity_under_vmap(kind, op):
+    """The engine vmaps the detection: a batch of three layouts of one
+    shape reduces as each would alone."""
+    rng = np.random.default_rng(5)
+    ids = []
+    for _ in range(3):
+        i, nseg = _ends_layout(kind, rng)
+        ids.append(i)
+    ids = jnp.asarray(np.stack(ids))
+    v = jnp.asarray(rng.normal(size=ids.shape + (2,)).astype(np.float32))
+    outs = {impl: np.asarray(jax.vmap(
+        lambda x, i, impl=impl: ops.segreduce_sorted(
+            x, i, nseg, op=op, impl=impl, block_m=1024))(v, ids))
+        for impl in IMPLS}
+    ref_out = np.asarray(jax.vmap(
+        lambda x, i: ref.segreduce_sorted_ref(x, i, nseg, op=op))(v, ids))
+    for impl, out in outs.items():
+        np.testing.assert_array_equal(out, ref_out, err_msg=impl)
+
+
+@pytest.mark.parametrize("m,nseg,d,opened,absent", [
+    # Graph500 scale 15: local move's pass A over the run ids, and pass B
+    # over the vertex ids
+    (882692, 882692, 2, "ends_mark", "searchsorted"),
+    (882692, 32769, 2, "ends_mark", "searchsorted"),
+    # Girvan-Newman graphs in the (256, 8192) and (256, 2048) buckets
+    (8192, 256, 1, "ends_search", "ends_mark"),
+    (2048, 256, 1, "ends_mark", "searchsorted"),
+])
+def test_segreduce_takes_the_placement_its_shape_calls_for(
+        m, nseg, d, opened, absent):
+    fn = jax.jit(lambda v, i: ops.segreduce_sorted(v, i, nseg,
+                                                   impl="pallas"))
+    text = fn.lower(jax.ShapeDtypeStruct((m, d), jnp.float32),
+                    jax.ShapeDtypeStruct((m,), jnp.int32)
+                    ).as_text(debug_info=True)
+    assert opened in text and absent not in text
 
 
 def test_segreduce_refine_masked_graph_runs():

@@ -68,6 +68,23 @@ def test_segscan_compiles_for_v5e(one_chip, op, dtype, d, m):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_kron_pass_a_reduction_compiles_for_v5e(one_chip, monkeypatch):
+    """Local move's pass A on a Graph500 scale-15 graph: the run sums of
+    882,692 entries over as many run ids, the folds placed by end mark."""
+    from repro.kernels import ops, segsum
+
+    monkeypatch.setattr(segsum, "_default_interpret",
+                        lambda i: False if i is None else i)
+    m = 882692
+    fn = jax.jit(lambda v, i: ops.segreduce_sorted(v, i, m, impl="pallas"))
+    compiled = fn.lower(
+        jax.ShapeDtypeStruct((m, 2), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "ends_mark" in text and "searchsorted" not in text
+
+
 def test_engine_detect_program_compiles_for_v5e(one_chip, monkeypatch):
     """The service engine's vmapped detect program for the sortscan
     bucket, tiled by 8 as on an accelerator, with the compiled kernels."""
