@@ -1,10 +1,11 @@
 #!/usr/bin/env python3
 """Readings of the control and of the planted faults, at a cell's own size.
 
-    python3 bench/controls.py --workload <cell> --kinds control,stale \
+    python3 bench/controls.py --workload <cell> [--kinds control,stale] \
         --seeds 11,12,13 --seconds 10
 
-For each kind of ``harness.faults.KINDS`` and each seed, runs the cell as
+For each kind (by default the cell's own: its mix's ``faults``, each a file
+``faults/<kind>.py``) and each seed, runs the cell as
 ``bench/run.py`` does, with that fault planted under the timed path, and
 prints one JSON line: the kind, the seed, ``correct`` and every number
 read, compared or not.  All runs share this one process, so each program compiles
@@ -24,7 +25,8 @@ ROOT = BENCH.parent
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--kinds", default="control,stale,altered")
+    ap.add_argument("--kinds", help="comma-separated fault kinds "
+                    "(default: the cell's own)")
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=10.0)
     args = ap.parse_args(argv)
@@ -33,9 +35,11 @@ def main(argv=None) -> int:
     from harness.device import configure
 
     configure(BENCH, ROOT)
-    from harness import faults, runner
+    from harness import faults, manifest, runner
 
-    for kind in (k for k in args.kinds.split(",") if k):
+    kinds = (args.kinds.split(",") if args.kinds is not None
+             else manifest.load_cell(args.workload, ROOT).faults)
+    for kind in (k for k in kinds if k):
         for seed in (int(s) for s in args.seeds.split(",")):
             t0 = time.perf_counter()
             with faults.planted(kind):

@@ -1,35 +1,27 @@
-"""Host-side checks of a partition, independent of the system under test.
+"""Host-side checks of an answer, independent of the system under test.
 
-Every function reads the benchmark's own canonical undirected edge list
+Every check reads the benchmark's own canonical undirected edge list
 ``(n, lo, hi, w)`` (``bench/traffic/generators.py``), never the program's
 graph container.  Modularity follows the paper's convention: both
 directions of every edge count, ``Q = sum_c [in_c / 2m - (tot_c / 2m)^2]``.
 
-The numbers a run compares (names as printed in the result's ``checks``):
-
-* ``disconnected`` -- communities whose members do not form one connected
-  component of the subgraph induced by the community's own edges; the
-  configuration's guarantee is 0 (the exact limit).
-* ``q_gap`` -- the widest gap between the modularity the program reported
-  for a partition and that partition's modularity recomputed here in
-  float64.
-* ``q_shortfall`` -- the widest shortfall, in modularity points, of a
-  served partition's modularity below that of the plain reference Louvain
-  on the same graph, ``Q_ref - Q``.
-* ``q_ref_gap`` -- the widest gap, either way, between the modularity the
-  program reported and that of the plain reference Louvain on the same
-  graph, ``|Q_ref - Q_reported|``.
-
-Every number is read in every run; a configuration's ``checks`` names the
-ones it compares, each with its limit.
+Each number a run reads is one file, ``checks/<name>.py``, whose
+``read(rec)`` takes one answer's :class:`Answer` and returns its reading,
+or ``None`` where the record lacks what it reads.  Every check file is read
+on every answer; a configuration's ``checks`` names the ones it compares,
+each with its limit, and they are printed under those names in the
+result's ``checks``.  So a check is added as one file.
 """
 from __future__ import annotations
+
+import dataclasses
+import pathlib
 
 import numpy as np
 from scipy.sparse import coo_matrix
 from scipy.sparse.csgraph import connected_components
 
-CHECK_NAMES = ("disconnected", "q_gap", "q_shortfall", "q_ref_gap")
+from harness import manifest
 
 
 def disconnected(n: int, lo, hi, labels) -> int:
@@ -59,16 +51,33 @@ def modularity(n: int, lo, hi, w, labels, dtype=np.float64) -> float:
     return float(inside / two_m - np.add.reduce(frac * frac, dtype=dtype))
 
 
+@dataclasses.dataclass(frozen=True)
+class Answer:
+    """One answer as a check reads it."""
+
+    graph: tuple | None     # the bench's canonical (n, lo, hi, w)
+    labels: object          # the answer's labels (None: not at hand)
+    q_reported: float       # the modularity the program reported
+    q: float                # the labels' modularity, float64, on the host
+    q_ref: float | None = None   # the reference's, where compared with it
+    extra: dict = dataclasses.field(default_factory=dict)  # the driver's
+
+
 class CheckTally:
-    """The worst reading of each number over every checked answer; the
+    """The worst reading of each check file over every answer; the
     numbers of ``limits`` are the ones compared."""
 
-    def __init__(self, limits: dict):
-        unknown = set(limits) - set(CHECK_NAMES)
+    def __init__(self, limits: dict,
+                 bench_dir: pathlib.Path = manifest.BENCH_DIR):
+        names = manifest.names(bench_dir, "checks")
+        unknown = set(limits) - set(names)
         if unknown or not limits:
-            raise KeyError(f"checks {sorted(unknown)}; known: {CHECK_NAMES}")
+            raise KeyError(f"checks {sorted(unknown)}; check files in "
+                           f"{bench_dir / 'checks'}: {names}")
         self.limits = {k: float(v) for k, v in limits.items()}
-        self.worst = {k: None for k in CHECK_NAMES}
+        self.readers = {k: manifest.load_module(bench_dir, "checks", k).read
+                        for k in names}
+        self.worst = {k: None for k in names}
         self.n_checked = 0
         self.n_reference = 0
 
@@ -77,23 +86,31 @@ class CheckTally:
         if cur is None or value > cur or np.isnan(value):
             self.worst[name] = float(value)
 
-    def answer(self, graph, labels, q_reported: float) -> float:
-        """Check one answer on ``graph = (n, lo, hi, w)``; returns its
-        float64 modularity."""
+    def read(self, rec: Answer) -> None:
+        """Every check's reading of one answer."""
+        for name, read in self.readers.items():
+            value = read(rec)
+            if value is not None:
+                self._note(name, value)
+        if rec.q_ref is not None:
+            self.n_reference += 1
+
+    def answer(self, graph, labels, q_reported: float, **extra) -> float:
+        """Check one answer on ``graph = (n, lo, hi, w)``, with the
+        driver's ``extra`` fields; returns its float64 modularity."""
         n, lo, hi, w = graph
-        self._note("disconnected", disconnected(n, lo, hi, labels))
         q = modularity(n, lo, hi, w, labels)
-        self._note("q_gap", abs(float(q_reported) - q))
+        self.read(Answer(graph, labels, float(q_reported), q, extra=extra))
         self.n_checked += 1
         return q
 
     def against_reference(self, q: float, q_reported: float,
                           q_ref: float) -> None:
-        """One answer (modularity ``q`` recomputed, ``q_reported`` by the
-        program) against the reference's ``q_ref`` on the same graph."""
-        self._note("q_shortfall", q_ref - q)
-        self._note("q_ref_gap", abs(q_ref - float(q_reported)))
-        self.n_reference += 1
+        """One answer already read by :meth:`answer` (modularity ``q``
+        recomputed, ``q_reported`` by the program) against the reference's
+        ``q_ref`` on the same graph: its record holds no graph or labels,
+        and a check that reads neither reads the same value again."""
+        self.read(Answer(None, None, float(q_reported), q, q_ref))
 
     def correct(self) -> bool:
         """Every compared number read and within its limit (NaN fails)."""

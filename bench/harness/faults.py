@@ -2,36 +2,30 @@
 program's place: each must turn a run's ``correct`` false.
 
 Used by ``bench/controls.py`` (readings on the chip at a cell's own size)
-and by ``bench/tests`` (the same at a size a test run holds).  Each is a
-context manager that patches the system under test for its duration and
-clears JAX's caches on the way in and out, so no program traced before it
-(or with it) is replayed.
+and by ``bench/tests`` (the same at a size a test run holds).  Each kind is
+one file, ``faults/<kind>.py``, whose ``plant()`` is a context manager that
+patches the seam it names for its duration (with :func:`patched`);
+:func:`planted` clears JAX's caches on the way in and out, so no program
+traced before it (or with it) is replayed.  A cell's kinds are the list
+``faults`` of its traffic mix, so a new cell's faults are new files and a
+list in its mix.
 
-* ``control`` -- the plain reference computed in bfloat16, the nearest
-  precision below the float32 the configurations state, answers in place
-  of the program's (labels and reported modularity);
-* ``stale`` -- the local-move step returns its state unchanged (every
-  vertex stays in its own community);
-* ``altered`` -- every answer altered where it is produced: a tenth of the
-  vertices take another vertex's community, the reported modularity kept;
-* ``half`` -- half of each engine batch left out: the second half of the
-  batch gets the answers of the first half (batched cells only).
+This file keeps what several kinds share: the patch itself, the control's
+bfloat16 answer and the alteration of an answer.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import importlib
+import pathlib
 
 import ml_dtypes
 import numpy as np
 
-from harness import checks, reference
-
-KINDS = ("control", "stale", "altered", "half")
+from harness import checks, manifest, reference
 
 
-def _edge_list(g):
+def edge_list(g):
     """The undirected edge list of a program graph (host copy)."""
     n = int(np.asarray(g.n_nodes))
     src, dst = np.asarray(g.src), np.asarray(g.dst)
@@ -40,8 +34,10 @@ def _edge_list(g):
     return n, src[keep].astype(np.int64), dst[keep].astype(np.int64), w[keep]
 
 
-def _bf16_answer(g, nv: int):
-    n, lo, hi, w = _edge_list(g)
+def bf16_answer(g, nv: int):
+    """The plain reference's labels (padded to ``nv``) and modularity for
+    the program graph ``g``, every weight and sum in bfloat16."""
+    n, lo, hi, w = edge_list(g)
     dt = ml_dtypes.bfloat16
     lab = reference.louvain(n, lo, hi, w, dtype=dt)
     q = checks.modularity(n, lo, hi, w, lab, dt) if lo.size else 0.0
@@ -50,7 +46,9 @@ def _bf16_answer(g, nv: int):
     return out, q
 
 
-def _alter(labels, n: int, seed: int = 0):
+def alter(labels, n: int, seed: int = 0):
+    """``labels`` with a tenth of the first ``n`` vertices given another
+    vertex's community."""
     lab = np.array(labels, copy=True)
     if n < 2:
         return lab
@@ -62,13 +60,43 @@ def _alter(labels, n: int, seed: int = 0):
 
 
 @contextlib.contextmanager
-def _patched(obj, name, value):
+def patched(obj, name, value):
+    """``obj.name`` is ``value`` for the duration."""
     old = getattr(obj, name)
     setattr(obj, name, value)
     try:
         yield
     finally:
         setattr(obj, name, old)
+
+
+@contextlib.contextmanager
+def each_detection(replace):
+    """Every answer of ``repro.core.detect`` and of the engine's
+    ``detect_batch`` passes through ``replace(g, labels, q) -> (labels,
+    q)`` where it is produced."""
+    import repro.core as core
+    from repro.service.engine import BatchedLouvainEngine
+
+    real_detect = core.detect
+    real_batch = BatchedLouvainEngine.detect_batch
+
+    def detect(g, **kw):
+        res = real_detect(g, **kw)
+        lab, q = replace(g, res.labels, res.modularity)
+        return dataclasses.replace(res, labels=lab, modularity=q)
+
+    def detect_batch(self, graphs, **kw):
+        graphs = list(graphs)
+        out = []
+        for g, r in zip(graphs, real_batch(self, graphs, **kw)):
+            lab, q = replace(g, r.C, r.q)
+            out.append(dataclasses.replace(r, C=lab, q=q))
+        return out
+
+    with patched(core, "detect", detect), \
+            patched(BatchedLouvainEngine, "detect_batch", detect_batch):
+        yield
 
 
 def _clear():
@@ -78,59 +106,11 @@ def _clear():
 
 
 @contextlib.contextmanager
-def planted(kind: str):
-    """Plant fault ``kind`` (one of :data:`KINDS`) for the duration."""
-    import repro.core as core
-    from repro.service.engine import BatchedLouvainEngine
-
-    real_detect = core.detect
-    real_batch = BatchedLouvainEngine.detect_batch
-
-    def detect(g, **kw):
-        res = real_detect(g, **kw)
-        nv = int(np.asarray(res.labels).shape[0])
-        if kind == "control":
-            lab, q = _bf16_answer(g, nv)
-            return dataclasses.replace(res, labels=lab, modularity=q)
-        return dataclasses.replace(
-            res, labels=_alter(res.labels, int(np.asarray(g.n_nodes))))
-
-    def detect_batch(self, graphs, **kw):
-        graphs = list(graphs)
-        if kind == "half":
-            h = -(-len(graphs) // 2)
-            res = real_batch(self, graphs[:h], **kw)
-            return [res[i % h] for i in range(len(graphs))]
-        res = real_batch(self, graphs, **kw)
-        out = []
-        for g, r in zip(graphs, res):
-            if kind == "control":
-                lab, q = _bf16_answer(g, r.C.shape[0])
-                out.append(dataclasses.replace(r, C=lab, q=q))
-            else:
-                out.append(dataclasses.replace(
-                    r, C=_alter(r.C, int(np.asarray(g.n_nodes)))))
-        return out
-
-    def stale_local_move(src, dst, w, C0, K, Sigma0, two_m, **_):
-        import jax.numpy as jnp
-
-        return C0, Sigma0, jnp.int32(1)
-
+def planted(kind: str, bench_dir: pathlib.Path = manifest.BENCH_DIR):
+    """Plant the fault of ``faults/<kind>.py`` for the duration."""
+    mod = manifest.load_module(bench_dir, "faults", kind)
     with contextlib.ExitStack() as stack:
-        if kind == "stale":
-            louvain_mod = importlib.import_module("repro.core.louvain")
-            stack.enter_context(_patched(louvain_mod, "local_move",
-                                         stale_local_move))
-        elif kind in ("control", "altered"):
-            stack.enter_context(_patched(core, "detect", detect))
-            stack.enter_context(_patched(BatchedLouvainEngine,
-                                         "detect_batch", detect_batch))
-        elif kind == "half":
-            stack.enter_context(_patched(BatchedLouvainEngine,
-                                         "detect_batch", detect_batch))
-        else:
-            raise ValueError(f"unknown fault {kind!r}; known: {KINDS}")
+        stack.enter_context(mod.plant())
         _clear()
         stack.callback(_clear)
         yield

@@ -9,10 +9,18 @@ Each name leads to a file of its own under the benchmark directory:
 * a graph family: the configuration's ``family`` names
   ``families/<family>.py`` (a function ``make(spec, seed, count)``);
 * a metric, end-to-end or per-layer: ``metrics/<metric>.py`` (a function
-  ``read(ctx)``).
+  ``read(ctx)``);
+* a check, compared or only read: ``checks/<check>.py`` (a function
+  ``read(rec)``, ``harness/checks.py``); the configuration's ``checks``
+  names the ones it compares, with their limits;
+* a planted fault: ``faults/<kind>.py`` (a context manager ``plant()``,
+  ``harness/faults.py``); the mix's ``faults`` lists the cell's kinds;
+* a tiny copy for the CPU tests: ``tests/tiny/<dir>/<name>.json``, the keys
+  that replace those of ``<dir>/<name>.json`` (``tests/bench_tiny_cells.py``).
 
-So a cell, a mix, a driver, a family or a metric is added by adding files
-and entries, with no edit to a file that is already there.
+So a cell, a mix, a driver, a family, a metric, a check, a fault or a tiny
+copy is added by adding files and entries, with no edit to a file that is
+already there.
 """
 from __future__ import annotations
 
@@ -46,6 +54,11 @@ class Cell:
         mod = load_module(self.bench_dir, "families", self.config["family"])
         return mod.make(self.config, seed, count)
 
+    @property
+    def faults(self) -> tuple:
+        """The kinds of fault the cell can have (its mix's ``faults``)."""
+        return tuple(self.traffic["faults"])
+
 
 def _reports(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
@@ -73,11 +86,17 @@ def load_cell(name: str, root: pathlib.Path,
         bench_dir=bench_dir)
 
 
+def names(bench_dir: pathlib.Path, kind: str) -> list:
+    """The names of the files ``<bench_dir>/<kind>/<name>.py``."""
+    return sorted(p.stem for p in (bench_dir / kind).glob("*.py"))
+
+
 def load_module(bench_dir: pathlib.Path, kind: str, name: str):
     """The module ``<bench_dir>/<kind>/<name>.py``."""
     path = bench_dir / kind / f"{name}.py"
     if not path.is_file():
-        raise KeyError(f"no {kind} file {path.name!r} in {path.parent}")
+        raise KeyError(f"no {kind} file {path.name!r} in {path.parent}; "
+                       f"found: {names(bench_dir, kind)}")
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{re.sub(r'[^A-Za-z0-9_]', '_', name)}", path)
     mod = importlib.util.module_from_spec(spec)

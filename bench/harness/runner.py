@@ -89,7 +89,7 @@ def run_with_tally(workload: str, seed: int, seconds: float, trace: bool, *,
 
     drv.release()
     t_chk = time.perf_counter()
-    tally = checks.CheckTally(cell.config["checks"])
+    tally = checks.CheckTally(cell.config["checks"], bench_dir)
     drv.check(reqs, tally)
     log(f"checked {tally.n_checked} answers, {tally.n_reference} against "
         f"the reference, in {time.perf_counter() - t_chk:.1f} s")

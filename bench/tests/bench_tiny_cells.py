@@ -1,5 +1,9 @@
 """Tiny copies of the benchmark's cells, built from data files alone, for
-tests that drive a whole run on the CPU, with the look for a chip faked."""
+tests that drive a whole run on the CPU, with the look for a chip faked.
+
+A tiny copy is a file ``tests/tiny/<dir>/<name>.json`` beside these tests:
+its keys replace those of the benchmark's ``<dir>/<name>.json`` (a
+configuration or a traffic mix) in the copy."""
 import contextlib
 import json
 import pathlib
@@ -13,35 +17,22 @@ for p in (str(BENCH), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-TINY_CONFIG = {
-    "graph500-s15": {"scale": 8},
-    "girvan-newman-128": {"groups": 2, "group": 16, "degree": 8,
-                          "z_out": [1, 2],
-                          "service": {"batch_size": 4,
-                                      "buckets": [[64, 512], [64, 2048]]}},
-}
-TINY_TRAFFIC = {"closed64": {"clients": 8, "pool": 24, "warm_seconds": 0.5,
-                             "reference_sample": 8}}
 
-
-def tiny_root(tmp: pathlib.Path) -> pathlib.Path:
-    """A checkout-shaped directory whose cells are the benchmark's own,
-    cut to a size a test run holds."""
+def tiny_root(tmp: pathlib.Path, source: pathlib.Path = ROOT) -> pathlib.Path:
+    """A checkout-shaped directory whose cells are those of ``source``'s
+    ``BENCHMARK.json`` and ``bench/``, each cut to the size its tiny files
+    give."""
+    src = source / "bench"
     bench = tmp / "bench"
-    for sub in ("configs", "traffic", "metrics", "drivers", "families"):
-        shutil.copytree(BENCH / sub, bench / sub)
-    shutil.copy(BENCH / "peaks.json", bench / "peaks.json")
-    shutil.copy(ROOT / "BENCHMARK.json", tmp / "BENCHMARK.json")
-    for name, change in TINY_CONFIG.items():
-        path = bench / "configs" / f"{name}.json"
-        cfg = json.loads(path.read_text())
-        cfg.update(change)
-        path.write_text(json.dumps(cfg))
-    for name, change in TINY_TRAFFIC.items():
-        path = bench / "traffic" / f"{name}.json"
-        mix = json.loads(path.read_text())
-        mix.update(change)
-        path.write_text(json.dumps(mix))
+    shutil.copytree(src, bench, ignore=shutil.ignore_patterns(
+        "tests", "harness", "__pycache__", ".autotune"))
+    shutil.copy(source / "BENCHMARK.json", tmp / "BENCHMARK.json")
+    tiny = src / "tests" / "tiny"
+    for change in sorted(tiny.rglob("*.json")):
+        path = bench / change.relative_to(tiny)
+        data = json.loads(path.read_text())
+        data.update(json.loads(change.read_text()))
+        path.write_text(json.dumps(data))
     return tmp
 
 

@@ -1,8 +1,9 @@
-"""Cells, configurations, mixes and metrics are found by name, and a cell
-can be added from data files alone."""
+"""Cells, configurations, mixes, metrics, checks, faults and tiny copies
+are found by name, and a cell can be added from data files alone."""
 import json
 import pathlib
 import re
+import shutil
 import sys
 
 import pytest
@@ -141,3 +142,151 @@ def test_family_driver_mix_and_metric_added_from_files_alone(tmp_path):
     assert res["correct"], res["checks"]
     assert res["metrics"]["detections_done"]["value"] == 2.0
     assert set(res["metrics"]) == {"setup_s", "detections_done"}
+
+
+REWEIGH_DRIVER = '''"""Warm updates to one held graph: a driver added as one file.
+
+Each request adds weight 1 to ``per_batch`` existing edges drawn from the
+seed through ``repro.core.update_communities``; its answer is the new
+partition, the modularity the program reports for it and the program's
+edge set, checked against the bench's host mirror of the graph."""
+import time
+
+import numpy as np
+
+from harness.drivers import program_graph
+from harness.faults import edge_list
+from harness.window import Request
+from traffic.generators import rng_for
+
+
+class Driver:
+    def __init__(self, cell, seed, *, annotate_on=False):
+        self.cell, self.traffic = cell, cell.traffic
+        self.rng = rng_for(seed, 3)
+        (self.graph,) = cell.make_graphs(seed, 1)
+
+    def _batch(self):
+        lo, hi = self.graph[1], self.graph[2]
+        pick = self.rng.choice(lo.size, size=self.traffic["per_batch"],
+                               replace=False)
+        return pick, (lo[pick], hi[pick], np.ones(pick.size, np.float32))
+
+    def setup(self):
+        import jax
+        import repro.core as core
+
+        self.g = jax.device_put(program_graph(self.graph))
+        self.C = core.detect(self.g).labels
+        _, batch = self._batch()
+        np.asarray(core.update_communities(self.g, self.C, batch)[1])
+
+    def window(self, t0, seconds, on_start=None):
+        import repro.core as core
+
+        n, lo, hi, w = self.graph
+        w = w.copy()
+        reqs = []
+        for _ in range(self.traffic["batches"]):
+            pick, batch = self._batch()
+            r = Request(t_due=time.perf_counter())
+            self.g, self.C, stats = core.update_communities(self.g, self.C,
+                                                            batch)
+            w[pick] += 1.0
+            r.info.update(graph=(n, lo, hi, w.copy()),
+                          labels=np.asarray(self.C), q=float(stats["q"]),
+                          edges=edge_list(self.g))
+            r.t_done = time.perf_counter()
+            reqs.append(r)
+        return reqs
+
+    def work_each(self):
+        return 1.0
+
+    def release(self):
+        del self.g, self.C
+
+    def check(self, reqs, tally):
+        for r in reqs:
+            tally.answer(r.info["graph"], r.info["labels"], r.info["q"],
+                         edges=r.info["edges"])
+'''
+
+EDGE_SET_CHECK = '''"""Edges, with their weights, in which the program's graph after an
+update differs from the bench's host mirror: a check added as one file."""
+
+
+def read(rec):
+    if "edges" not in rec.extra:
+        return None
+    _, lo, hi, w = rec.graph
+    _, plo, phi, pw = rec.extra["edges"]
+    mirror = set(zip(lo.tolist(), hi.tolist(), w.tolist()))
+    program = set(zip(plo.tolist(), phi.tolist(), pw.tolist()))
+    return float(len(mirror ^ program))
+'''
+
+WARM_ALTERED_FAULT = '''"""Every warm update's labels altered where they are produced: a fault
+added as one file (``repro.core.update_communities``)."""
+import numpy as np
+
+from harness.faults import alter, patched
+
+
+def plant():
+    import repro.core as core
+
+    real = core.update_communities
+
+    def update_communities(g_old, C_prev, updates, **kw):
+        g, C, stats = real(g_old, C_prev, updates, **kw)
+        return g, alter(C, int(np.asarray(g.n_nodes))), stats
+
+    return patched(core, "update_communities", update_communities)
+'''
+
+
+def test_check_fault_and_tiny_copy_added_from_files_alone(tmp_path):
+    source = tmp_path / "source"
+    shutil.copytree(BENCH, source / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", ".autotune"))
+    shutil.copy(ROOT / "BENCHMARK.json", source / "BENCHMARK.json")
+    bench = source / "bench"
+    (bench / "drivers" / "reweigh.py").write_text(REWEIGH_DRIVER)
+    (bench / "checks" / "edge_set.py").write_text(EDGE_SET_CHECK)
+    (bench / "faults" / "warm_altered.py").write_text(WARM_ALTERED_FAULT)
+    cfg = json.loads((bench / "configs" / "graph500-s15.json").read_text())
+    cfg.update(scale=12, checks={"disconnected": 0, "q_gap": 1e-4,
+                                 "edge_set": 0})
+    (bench / "configs" / "graph500-warm.json").write_text(json.dumps(cfg))
+    (bench / "tests" / "tiny" / "configs" / "graph500-warm.json").write_text(
+        json.dumps({"scale": 6}))
+    (bench / "traffic" / "reweigh3.json").write_text(json.dumps(
+        {"driver": "reweigh", "batches": 3, "per_batch": 4,
+         "faults": ["warm_altered"]}))
+    manifest = json.loads((source / "BENCHMARK.json").read_text())
+    manifest["configs"].append({"name": "graph500-warm", "source": "x",
+                                "file": "bench/configs/graph500-warm.json",
+                                "reduced": ["scale"], "why": "x"})
+    manifest["workloads"].append({"name": "warm-update",
+                                  "config": "graph500-warm",
+                                  "traffic": "reweigh3", "chips": 1,
+                                  "why": "x"})
+    p95 = next(m for m in manifest["end_to_end"] if m["name"] == "p95_ms")
+    p95["workloads"].append("warm-update")
+    (source / "BENCHMARK.json").write_text(json.dumps(manifest))
+
+    root = tiny_root(tmp_path / "tiny", source)
+    cell = load_cell("warm-update", root, root / "bench")
+    assert cell.config["scale"] == 6 and cell.faults == ("warm_altered",)
+    res = run_tiny(root, "warm-update", seconds=30.0)
+    assert res["correct"], res["checks"]
+    assert res["checks"]["edge_set"] == {"value": 0.0, "limit": 0.0}
+    assert set(res["metrics"]) == {"setup_s", "p95_ms"}
+
+    from harness import faults
+
+    with faults.planted("warm_altered", root / "bench"):
+        res = run_tiny(root, "warm-update", seconds=30.0)
+    assert not res["correct"], res["checks"]
+    assert res["checks"]["edge_set"]["value"] == 0.0
